@@ -20,6 +20,9 @@ WORKER = textwrap.dedent(
     os.environ["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={spec.get('devices', 1)}"
     import numpy as np
     from repro.api import GraphStats, Resources, TriangleCounter, plan
+    from repro.utils import enable_compile_cache
+
+    enable_compile_cache()
 
     from repro.graphs.datasets import load
 
@@ -37,7 +40,7 @@ WORKER = textwrap.dedent(
         if devices > 1:
             from repro.launch.mesh import make_ring_mesh
             mesh = make_ring_mesh(devices)
-        counter = TriangleCounter(Resources(n_devices=devices), mesh=mesh)
+        counter = TriangleCounter(Resources.detect(), mesh=mesh)
         p = plan(GraphStats.from_graph(g), counter.resources, allow=allow)
         res = counter.count(g, plan=p)
         count = res.item()

@@ -13,7 +13,8 @@ On hosts without a TPU the Pallas kernels run in interpret mode; their
 absolute timings are not hardware numbers, but the grid-step counts are
 exact and the interpret-mode wall-clock scales with them, so the dead-block
 win is still visible end-to-end. Run with ``--quick`` for the CI smoke
-variant (small shapes, interpret mode, 3 reps).
+variant (small shapes, 3 reps). On a TPU the kernels run compiled
+(``repro.utils.interpret_mode`` decides from the backend).
 
 Usage: PYTHONPATH=src python benchmarks/kernel_bench.py [--quick] [--out F]
 """
@@ -45,6 +46,7 @@ from repro.graphs import generators as gen
 from repro.kernels.bitset_count.bitset_count import bitset_edge_count_per_edge_kernel
 from repro.kernels.bitset_count.ops import bitset_edge_count, bitset_grid_steps
 from repro.kernels.triangle_count.ops import triangle_count, triangle_count_grid_steps
+from repro.utils import enable_compile_cache, interpret_mode
 
 DEFAULT_OUT = os.path.join(os.path.dirname(__file__), "..", "BENCH_kernels.json")
 
@@ -75,7 +77,7 @@ def bench_kernels(*, quick: bool = False, reps: int | None = None) -> list[dict]
     u = jnp.asarray(forward_adjacency_dense(g))
     for method, live in (("full_grid_seed", False), ("live_grid", True)):
         ms = _median_ms(
-            lambda live=live: triangle_count(u, block=block, interpret=True, live_grid=live),
+            lambda live=live: triangle_count(u, block=block, live_grid=live),
             reps=reps,
         )
         records.append({
@@ -90,10 +92,11 @@ def bench_kernels(*, quick: bool = False, reps: int | None = None) -> list[dict]
     _, masks, edge_blocks = build_bitset_ring_operands(gb, 1)
     mask, eb = jnp.asarray(masks[0]), jnp.asarray(edge_blocks[0])
     b = int(eb.shape[0])
-    seed_fn = jax.jit(partial(bitset_edge_count_per_edge_kernel, interpret=True))
+    seed_fn = jax.jit(partial(bitset_edge_count_per_edge_kernel,
+                              interpret=interpret_mode()))
     runs = (
         ("per_edge_seed", lambda: seed_fn(mask, eb), b),
-        ("blocked_tile128", lambda: bitset_edge_count(mask, eb, edge_tile=128, interpret=True),
+        ("blocked_tile128", lambda: bitset_edge_count(mask, eb, edge_tile=128),
          bitset_grid_steps(b, edge_tile=128)),
     )
     for method, fn, steps in runs:
@@ -268,11 +271,12 @@ def run(verbose: bool = True) -> list[dict]:
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--quick", action="store_true",
-                    help="CI smoke: small shapes, interpret mode, 3 reps")
+                    help="CI smoke: small shapes, 3 reps")
     ap.add_argument("--out", default=DEFAULT_OUT, help="BENCH_kernels.json path")
     ap.add_argument("--skip-legacy", action="store_true",
                     help="only the kernel trajectory, skip the XLA-path table")
     args = ap.parse_args()
+    enable_compile_cache()
 
     records = bench_kernels(quick=args.quick)
     path = write_bench_json(records, args.out)

@@ -1,15 +1,53 @@
 # One function per paper table/figure. Prints ``name,us_per_call,derived`` CSV
 # at the end (harness contract) plus human-readable sections per figure.
+#
+# This process never touches JAX: every suite that computes runs in a child
+# process of its own, one after another, so on a TPU host each child in turn
+# is the only holder of the chip.
 from __future__ import annotations
 
 import argparse
 import json
 import os
+import subprocess
 import sys
 
-sys.path.insert(0, os.path.abspath(os.path.join(os.path.dirname(__file__), "..")))
+ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
+sys.path.insert(0, ROOT)
 
-from benchmarks import fig10_11_et_vm, fig12_13_cores, kernel_bench, roofline, table1_suite  # noqa: E402
+from benchmarks import fig10_11_et_vm, fig12_13_cores  # noqa: E402
+
+# Table 1 and the kernel micro-benchmarks compute in-process; they run here,
+# in one child, and hand their CSV rows back on a tagged stdout line.
+_LOCAL_SUITES = """
+import json
+from benchmarks import kernel_bench, roofline, table1_suite
+from repro.utils import enable_compile_cache
+
+enable_compile_cache()
+print("== Table 1: graph benchmark (scaled instantiation + exactness) ==")
+table1_suite.run()
+print("\\n== Kernel/path micro-benchmarks ==")
+rows = kernel_bench.run()
+print("\\n== Roofline (from dry-run artifacts, if present) ==")
+roofline.print_table("pod_16x16")
+print("CSV_ROWS " + json.dumps(rows, default=str), flush=True)
+"""
+
+
+def _run_local_suites() -> list[dict]:
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(ROOT, "src"), ROOT, env.get("PYTHONPATH", "")])
+    proc = subprocess.run([sys.executable, "-c", _LOCAL_SUITES], env=env,
+                          cwd=ROOT, stdout=subprocess.PIPE, text=True, check=True)
+    rows: list[dict] = []
+    for line in proc.stdout.splitlines():
+        if line.startswith("CSV_ROWS "):
+            rows = json.loads(line[len("CSV_ROWS "):])
+        else:
+            print(line)
+    return rows
 
 
 def main() -> None:
@@ -18,13 +56,7 @@ def main() -> None:
     ap.add_argument("--timeout", type=float, default=150.0)
     args = ap.parse_args()
 
-    csv_rows: list[dict] = []
-
-    print("== Table 1: graph benchmark (scaled instantiation + exactness) ==")
-    table1_suite.run()
-
-    print("\n== Kernel/path micro-benchmarks ==")
-    csv_rows += kernel_bench.run()
+    csv_rows: list[dict] = _run_local_suites()
 
     results = {}
     if not args.fast:
@@ -45,10 +77,7 @@ def main() -> None:
                 csv_rows.append({"name": f"fig12_{r['graph']}_{r['method']}_x{r['devices']}",
                                  "us_per_call": r["wall_s"] * 1e6, "derived": ""})
 
-    print("\n== Roofline (from dry-run artifacts, if present) ==")
-    roofline.print_table("pod_16x16")
-
-    out_dir = os.path.join(os.path.dirname(__file__), "..", "results")
+    out_dir = os.path.join(ROOT, "results")
     os.makedirs(out_dir, exist_ok=True)
     with open(os.path.join(out_dir, "bench_results.json"), "w") as f:
         json.dump(results, f, indent=1, default=str)

@@ -75,6 +75,7 @@ from repro.core.streaming import ingest_trace_count
 from repro.core.triangle_ref import count_triangles_brute
 from repro.graphs import generators as gen
 from repro.serve.serve_loop import TriangleServer
+from repro.utils import enable_compile_cache
 
 DEFAULT_OUT = os.path.join(os.path.dirname(__file__), "..", "BENCH_kernels.json")
 
@@ -367,7 +368,13 @@ def _cluster_traces(server) -> int:
 
 def bench_cluster(*, quick: bool = False) -> list[dict]:
     """Multi-host tier: in-process multiplexer vs router + 2 worker
-    subprocesses on the same mixed workload, plus live-migration cost."""
+    subprocesses on the same mixed workload, plus live-migration cost.
+
+    The cluster runs first, while this process holds no device: on a TPU
+    host each worker owns one chip and the router none. The in-process
+    baseline runs after the workers are reaped, so it is the only holder
+    of the chip when it runs — call this before anything else in the
+    process touches JAX's devices."""
     from repro.serve.serve_loop import ClusterServer
 
     S = 8 if quick else 16
@@ -380,32 +387,27 @@ def bench_cluster(*, quick: bool = False) -> list[dict]:
     shape = f"S{S}/n{n}/m{m_total}/b{block}/w2"
     n_blocks_total = sum(len(b) for _, b, _ in streams)
 
-    local = TriangleServer()
+    def timed(method, server):
+        ms, out = timed_ms(
+            lambda: server.serve_streams(requests, block_size=block),
+            reps=reps, warmup=False, sync=lambda rs: [r.count for r in rs])
+        assert [r.item() for r in out] == wants
+        records.append({
+            "op": "serve_cluster", "shape": shape, "method": method,
+            "median_ms": round(ms, 3), "grid_steps": n_blocks_total,
+            "edges_per_s": round(m_total / (ms / 1e3)),
+        })
+        print(f"  {method:22s} {ms:9.1f} ms for {S} streams "
+              f"({m_total} edges, {records[-1]['edges_per_s']:,} edges/s)")
+
     state = 4 * n * (-(-n // 32))  # dense bitset per session
     specs = [{"memory_bytes": S * state}, {"memory_bytes": S * state}]
     records = []
     with ClusterServer(specs, checkpoint_every_bytes=None) as srv:
-        # warm both paths (workers compile their shared ingest trace once)
+        # warm (workers compile their shared ingest trace once)
         base = srv.serve_streams(requests, block_size=block)
         assert [r.item() for r in base] == wants, "cluster counts wrong"
-        ref = local.serve_streams(requests, block_size=block)
-        for a, b in zip(ref, base):
-            assert np.asarray(a.count) == np.asarray(b.count)  # bit-identical
-
-        for method, server in (("single_process", local),
-                               ("cluster_2workers", srv)):
-            # both servers were warmed by the parity pass above
-            ms, out = timed_ms(
-                lambda: server.serve_streams(requests, block_size=block),
-                reps=reps, warmup=False, sync=lambda rs: [r.count for r in rs])
-            assert [r.item() for r in out] == wants  # lint: disable=R2 -- verifying the last rep's counts after its clock stopped
-            records.append({
-                "op": "serve_cluster", "shape": shape, "method": method,
-                "median_ms": round(ms, 3), "grid_steps": n_blocks_total,
-                "edges_per_s": round(m_total / (ms / 1e3)),
-            })
-            print(f"  {method:22s} {ms:9.1f} ms for {S} streams "
-                  f"({m_total} edges, {records[-1]['edges_per_s']:,} edges/s)")
+        timed("cluster_2workers", srv)
 
         # forced mid-stream live migration: feed half, move one session to
         # the other worker, feed the rest — exact counts, zero new traces
@@ -436,6 +438,12 @@ def bench_cluster(*, quick: bool = False) -> list[dict]:
         })
         print(f"  {'live_migration':22s} {ms:9.1f} ms per migration "
               f"(checkpoint→evict→restore, {new_traces} new traces)")
+
+    local = TriangleServer()
+    ref = local.serve_streams(requests, block_size=block)  # warm
+    for a, b in zip(ref, base):
+        assert np.asarray(a.count) == np.asarray(b.count)  # bit-identical
+    timed("single_process", local)
     return records
 
 
@@ -469,14 +477,15 @@ def main() -> None:
     ap.add_argument("--skip-async", action="store_true",
                     help="skip the async double-buffered driver scenario")
     args = ap.parse_args()
-    print(f"serve_bench: backend={jax.default_backend()} quick={args.quick}")
+    enable_compile_cache()
     records = []
+    if not args.skip_cluster:  # first: its workers need the chips free
+        records += bench_cluster(quick=args.quick)
+    print(f"serve_bench: backend={jax.default_backend()} quick={args.quick}")
     if not args.skip_multiplex:
         records += bench_serve(quick=args.quick, n_streams=args.streams)
     if not args.skip_preempt:
         records += bench_preempt(quick=args.quick)
-    if not args.skip_cluster:
-        records += bench_cluster(quick=args.quick)
     if not args.skip_async:
         records += bench_async(quick=args.quick)
     path = merge_bench_json(records, args.out)

@@ -35,6 +35,7 @@ from common import timed_ms
 
 from repro.api import GraphStats, Resources, hybrid_sizing
 from repro.core.streaming import count_stream, count_stream_hybrid
+from repro.utils import enable_compile_cache
 
 DEFAULT_OUT = os.path.join(os.path.dirname(__file__), "..", "BENCH_kernels.json")
 
@@ -58,7 +59,7 @@ def bench_stream(*, quick: bool = False, reps: int | None = None) -> list[dict]:
 
     stats = GraphStats(n_nodes=n, n_edges=m, replication_factor=0,
                        max_degree=0, max_fwd_degree=0, edges_in_memory=False)
-    hyb = hybrid_sizing(stats, Resources())
+    hyb = hybrid_sizing(stats, Resources.detect())
     assert hyb is not None, "bench sizes must be past the hybrid break-even"
     block = hyb.block_size
     blocks = [edges[i:i + block] for i in range(0, m, block)]
@@ -126,6 +127,7 @@ def main() -> None:
     ap.add_argument("--out", default=DEFAULT_OUT,
                     help=f"BENCH json to merge into (default {DEFAULT_OUT})")
     args = ap.parse_args()
+    enable_compile_cache()
     print(f"stream_bench: backend={jax.default_backend()} quick={args.quick}")
     records = bench_stream(quick=args.quick)
     path = merge_bench_json(records, args.out)

@@ -37,6 +37,7 @@ import numpy as np
 from common import timed_ms
 
 from repro.core import streaming
+from repro.utils import enable_compile_cache
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 "..", "tests"))
@@ -159,6 +160,7 @@ def main() -> None:
     ap.add_argument("--out", default=DEFAULT_OUT,
                     help=f"BENCH json to merge into (default {DEFAULT_OUT})")
     args = ap.parse_args()
+    enable_compile_cache()
     print(f"stream_window_bench: backend={jax.default_backend()} "
           f"quick={args.quick}")
     records = bench_window(quick=args.quick, window=args.window)

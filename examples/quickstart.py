@@ -12,7 +12,7 @@ graph = gen.gnp(400, 0.3, seed=7)
 print(f"G(n={graph.n_nodes}, m={graph.n_edges}, density={graph.density:.3f})")
 
 # The planner turns measured input properties into an inspectable Plan.
-p = plan(GraphStats.from_graph(graph), Resources())
+p = plan(GraphStats.from_graph(graph), Resources.detect())
 print(f"plan: method={p.method} n_stages={p.n_stages} "
       f"predicted_bytes={p.predicted_bytes} ({p.reason})")
 

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.api.planner import GraphStats, Plan, Resources, plan as plan_fn
+from repro.utils import require_count_capacity, triangle_bound
 
 
 def bucket(x: int, minimum: int = 64) -> int:
@@ -96,6 +97,9 @@ class SessionCheckpoint:
     wall_s: float
     path: str | None = None
     disk_bytes: int | None = None
+    # edges fed per epoch the state still holds (one entry for an unbounded
+    # stream): carries the count-capacity guard across restore
+    edges_fed: tuple = (0,)
 
     @property
     def spilled(self) -> bool:
@@ -116,6 +120,7 @@ class SessionCheckpoint:
             "nbytes": self.nbytes, "buffer_shape": self.buffer_shape,
             "n_blocks": self.n_blocks,
             "n_epochs_advanced": self.n_epochs_advanced,
+            "edges_fed": list(self.edges_fed),
             "wall_s": self.wall_s})
         np.savez_compressed(path, __meta__=np.array(meta), **self.arrays)
         self.arrays, self.path = None, path
@@ -182,6 +187,7 @@ class SessionCheckpoint:
                    arrays=arrays, buffer_shape=meta["buffer_shape"],
                    n_blocks=meta["n_blocks"],
                    n_epochs_advanced=meta["n_epochs_advanced"],
+                   edges_fed=tuple(meta.get("edges_fed", (0,))),
                    wall_s=meta["wall_s"],
                    disk_bytes=int(os.path.getsize(path)))
 
@@ -204,7 +210,7 @@ class TriangleCounter:
 
     def __init__(self, resources: Resources | None = None, *,
                  plan: Plan | None = None, mesh=None):
-        self.resources = resources or Resources()
+        self.resources = resources or Resources.detect()
         self.fixed_plan = plan
         self.mesh = mesh
         self._cache: dict[tuple, _Entry] = {}
@@ -248,6 +254,10 @@ class TriangleCounter:
         executor = getattr(self, f"_run_{p.method}", None)
         if executor is None:
             raise ValueError(f"plan method {p.method!r} not executable here")
+        if p.method != "stream":  # a stream session guards its own feeds
+            require_count_capacity(triangle_bound(g.n_nodes, g.n_edges),
+                                   f"{p.method} count of a {g.n_nodes}-node, "
+                                   f"{g.n_edges}-edge graph")
         count, stats = executor(g, p)
         return CountResult(count=count, plan=p,
                            wall_s=time.perf_counter() - t0, stats=stats)
@@ -331,13 +341,15 @@ class TriangleCounter:
         budgeting admission charge it exactly like a fresh open."""
         from repro.core import streaming
 
+        on_mesh = self.mesh_matches(ckpt.plan.n_stages)
         session = StreamSession(
-            self, ckpt.n_nodes, ckpt.plan, ckpt.block_size,
-            self.mesh_matches(ckpt.plan.n_stages),
-            state=streaming.restore_state(ckpt.load_arrays()))
+            self, ckpt.n_nodes, ckpt.plan, ckpt.block_size, on_mesh,
+            state=streaming.restore_state(ckpt.load_arrays(),
+                                          mesh=self.mesh if on_mesh else None))
         session._buffer.import_shape_state(ckpt.buffer_shape)
         session.n_blocks = ckpt.n_blocks
         session.n_epochs_advanced = ckpt.n_epochs_advanced
+        session.edges_fed = list(ckpt.edges_fed)
         session._wall = ckpt.wall_s
         session.restored = True
         return session
@@ -416,23 +428,22 @@ class TriangleCounter:
             if p.n_stages > 1:
                 if on_mesh:
                     return streaming.make_mesh_ingest_windowed(
-                        self.mesh, use_kernel=p.use_kernel, interpret=p.interpret)
+                        self.mesh, use_kernel=p.use_kernel)
                 return streaming.ingest_block_windowed_sharded_donated
             return _partial(streaming.ingest_block_windowed_donated,
-                            use_kernel=p.use_kernel, interpret=p.interpret)
+                            use_kernel=p.use_kernel)
         if p.n_stages > 1:
             if on_mesh:
                 return streaming.make_mesh_ingest(
-                    self.mesh, use_kernel=p.use_kernel, interpret=p.interpret)
+                    self.mesh, use_kernel=p.use_kernel)
             return streaming.ingest_block_sharded_donated
-        return _partial(streaming.ingest_block_donated, use_kernel=p.use_kernel,
-                        interpret=p.interpret)
+        return _partial(streaming.ingest_block_donated, use_kernel=p.use_kernel)
 
     def batch_plan(self) -> Plan:
         """The dense plan ``count_batch`` runs when none is given: derived
-        from ``self.resources`` so the backend decision (compiled Pallas
-        kernels on TPU vs interpret-mode XLA elsewhere) carries into batched
-        serving instead of silently reverting to the Plan defaults."""
+        from ``self.resources`` so the backend decision (the Pallas kernels
+        on TPU, the XLA formulation elsewhere) carries into batched serving
+        instead of silently reverting to the Plan defaults."""
         from repro.api.planner import backend_exec_flags
 
         res = self.resources
@@ -461,6 +472,9 @@ class TriangleCounter:
                 f"count_batch is the vmapped dense path; got a plan with "
                 f"method={p.method!r}")
         t0 = time.perf_counter()
+        require_count_capacity(
+            max(triangle_bound(g.n_nodes, g.n_edges) for g in graphs),
+            "batched dense count")
         n_b = bucket(max(g.n_nodes for g in graphs))
         b_b = bucket(len(graphs), minimum=8)
         us = np.zeros((b_b, n_b, n_b), np.float32)
@@ -494,8 +508,7 @@ class TriangleCounter:
 
         def body(u):
             entry.traces += 1
-            return count_triangles_dense(u, use_kernel=p.use_kernel,
-                                         interpret=p.interpret)
+            return count_triangles_dense(u, use_kernel=p.use_kernel)
 
         return jax.jit(body)
 
@@ -505,7 +518,7 @@ class TriangleCounter:
         def body(us):
             entry.traces += 1
             return jax.vmap(lambda u: count_triangles_dense(
-                u, use_kernel=p.use_kernel, interpret=p.interpret))(us)
+                u, use_kernel=p.use_kernel))(us)
 
         return jax.jit(body)
 
@@ -550,8 +563,7 @@ class TriangleCounter:
         pad_to = bucket(max(-(-g.n_nodes // p.n_stages), 1), minimum=8)
         part, blocks = build_dense_ring_operands(g, p.n_stages, balance=p.balance,
                                                  pad_to=pad_to)
-        spec = dense_ring_spec(part.rows_per_stage, use_kernel=p.use_kernel,
-                               interpret=p.interpret)
+        spec = dense_ring_spec(part.rows_per_stage, use_kernel=p.use_kernel)
         blocks = jnp.asarray(blocks)
         key = (p.cache_key(), ("ring", p.n_stages, part.rows_per_stage))
         if self.mesh_matches(p.n_stages):
@@ -574,7 +586,7 @@ class TriangleCounter:
         edge_block = bucket(max(-(-g.n_edges // p.n_stages), 1), minimum=128)
         part, masks, edges = build_bitset_ring_operands(
             g, p.n_stages, balance=p.balance, pad_to=pad_to, edge_block=edge_block)
-        spec = bitset_ring_spec(use_kernel=p.use_kernel, interpret=p.interpret)
+        spec = bitset_ring_spec(use_kernel=p.use_kernel)
         masks, edges = jnp.asarray(masks), jnp.asarray(edges)
         key = (p.cache_key(), ("bitset", p.n_stages) + tuple(masks.shape) + tuple(edges.shape))
         if self.mesh_matches(p.n_stages):
@@ -706,12 +718,14 @@ class StreamSession:
         elif plan.window_epochs:
             if plan.n_stages > 1:
                 self.state = streaming.init_windowed_sharded_state(
-                    n_nodes, plan.window_epochs, plan.n_stages)
+                    n_nodes, plan.window_epochs, plan.n_stages,
+                    mesh=counter.mesh if on_mesh else None)
             else:
                 self.state = streaming.init_windowed_state(
                     n_nodes, plan.window_epochs)
         elif plan.n_stages > 1:
-            self.state = streaming.init_sharded_state(n_nodes, plan.n_stages)
+            self.state = streaming.init_sharded_state(
+                n_nodes, plan.n_stages, mesh=counter.mesh if on_mesh else None)
         else:
             self.state = streaming.init_state(n_nodes)
         # per-device footprint: one column shard when a real mesh hosts the
@@ -721,6 +735,7 @@ class StreamSession:
         nbytes = self._state_nbytes()
         self.state_bytes = nbytes // plan.n_stages if on_mesh else nbytes
         self.n_blocks = 0
+        self.edges_fed = [0]  # per live epoch, newest last (see _admit_edges)
         self.n_epochs_advanced = 0
         self._traces0 = streaming.ingest_trace_count()
         self._wall = 0.0
@@ -754,7 +769,7 @@ class StreamSession:
             raise RuntimeError("session already finalized")
         from repro.core import streaming
 
-        edges = streaming.validate_edges(edges, self.n_nodes)
+        edges = self._admit_edges(edges)
         t0 = time.perf_counter()
         for b in self._buffer.push(edges):
             self.state = self._entry.fn(self.state, b)
@@ -778,18 +793,40 @@ class StreamSession:
         returned block through :meth:`ingest_ready` IN ORDER."""
         if self.result is not None:
             raise RuntimeError("session already finalized")
+        return self._buffer.push(self._admit_edges(edges))
+
+    def _admit_edges(self, edges):
+        """Front-door validation plus the count-capacity guard: the ingest
+        terms reach three times the triangles of the edges the state holds
+        (``dd`` counts each all-in-block triangle three times) — every edge
+        fed, or for a windowed stream those of its live epochs — so a feed
+        that could push them past ``count_dtype`` raises before it is
+        ingested instead of wrapping."""
         from repro.core import streaming
 
-        return self._buffer.push(
-            streaming.validate_edges(edges, self.n_nodes))
+        edges = streaming.validate_edges(edges, self.n_nodes)
+        held = sum(self.edges_fed) + len(edges)
+        require_count_capacity(3 * triangle_bound(self.n_nodes, held),
+                               f"stream of {self.n_nodes} nodes holding "
+                               f"{held} edges")
+        self.edges_fed[-1] += len(edges)
+        return edges
+
+    def _next_epoch_tally(self) -> None:
+        """Host-half epoch boundary: the guard's tally forgets the epoch the
+        window is about to expire."""
+        if self.plan.window_epochs:
+            self.edges_fed = (self.edges_fed + [0])[-self.plan.window_epochs:]
 
     def flush_ready(self):
         """PRODUCER half of an async tail flush: the padded tail block
         (None when nothing is buffered), NOT ingested. Used by the prefetch
         producer at an ``advance`` boundary so the epoch's tail enters the
-        device-ready queue in order before the expiry marker."""
+        device-ready queue in order before the expiry marker; the capacity
+        guard's tally moves to the next epoch here."""
         if self.result is not None:
             raise RuntimeError("session already finalized")
+        self._next_epoch_tally()
         return self._buffer.flush()
 
     def ingest_ready(self, block) -> None:
@@ -873,6 +910,7 @@ class StreamSession:
             nbytes=streaming.state_nbytes(arrays), arrays=arrays,
             buffer_shape=self._buffer.export_shape_state(),
             n_blocks=self.n_blocks, n_epochs_advanced=self.n_epochs_advanced,
+            edges_fed=tuple(self.edges_fed),
             wall_s=self._wall)
 
     def advance(self) -> None:
@@ -895,6 +933,7 @@ class StreamSession:
         from repro.core import streaming
 
         t0 = time.perf_counter()
+        self._next_epoch_tally()
         tail = self._buffer.flush()
         if tail is not None:
             self.state = self._entry.fn(self.state, tail)
@@ -965,7 +1004,7 @@ def default_counter() -> TriangleCounter:
 
 
 _METHOD_ALIASES = {"bitset": "bitset_ring"}
-_PLAN_KWARGS = {"n_stages", "use_kernel", "interpret", "balance",
+_PLAN_KWARGS = {"n_stages", "use_kernel", "balance",
                 "edge_batch", "node_batch", "block_size"}
 
 

@@ -115,27 +115,48 @@ class GraphStats:
         )
 
 
+# The budget a CPU backend plans against: XLA's CPU client reports no device
+# memory, and the tests size their cases against this figure.
+CPU_MEMORY_BYTES = 4 << 30
+
+
 @dataclasses.dataclass(frozen=True)
 class Resources:
-    """What the hardware offers: memory budget, ring width, kernel backend."""
+    """What the hardware offers: memory budget, ring width, kernel backend.
 
-    memory_bytes: int = 4 << 30
+    The fields are plain values, so planner tests can describe any machine.
+    Callers that run on the device present take :meth:`detect` — the
+    default of ``TriangleCounter``, ``TriangleServer`` and cluster workers."""
+
+    memory_bytes: int = CPU_MEMORY_BYTES
     n_devices: int = 1
     backend: str = "cpu"  # "tpu" turns on the Pallas kernels (compiled mode)
     max_stages: int | None = None  # defaults to n_devices
 
     @classmethod
-    def detect(cls) -> "Resources":
+    def detect(cls, **overrides) -> "Resources":
+        """The local devices as JAX reports them: ``backend`` from
+        ``jax.devices()[0].platform``, ``memory_bytes`` from that device's
+        ``memory_stats()["bytes_limit"]``. A CPU device reports no limit and
+        plans against ``CPU_MEMORY_BYTES``; any other backend without a
+        figure is an error. ``overrides`` replace detected fields (a worker's
+        operator-set budget, a ring-width cap)."""
         import jax
 
-        try:
-            import os
-
-            mem = os.sysconf("SC_PAGE_SIZE") * os.sysconf("SC_PHYS_PAGES")
-        except (ValueError, OSError, AttributeError):
-            mem = 4 << 30
-        return cls(memory_bytes=int(mem), n_devices=jax.local_device_count(),
-                   backend=jax.default_backend())
+        dev = jax.devices()[0]
+        stats = dev.memory_stats() or {}
+        mem = stats.get("bytes_limit")
+        if mem is None:
+            if dev.platform != "cpu":
+                raise RuntimeError(
+                    f"{dev.platform} device {dev.device_kind!r} reports no "
+                    f"memory_stats()['bytes_limit'] — refusing to plan "
+                    f"against a made-up budget")
+            mem = CPU_MEMORY_BYTES
+        fields = {"memory_bytes": int(mem), "n_devices": len(jax.devices()),
+                  "backend": dev.platform}
+        fields.update({k: v for k, v in overrides.items() if v is not None})
+        return cls(**fields)
 
 
 # Plan fields that inform ADMISSION/LOGGING only and are excluded from
@@ -161,7 +182,6 @@ class Plan:
     method: str
     n_stages: int = 1
     use_kernel: bool = False
-    interpret: bool = True
     balance: bool = True
     edge_batch: int = 4096  # sparse intersection batch
     node_batch: int = 256  # mapreduce reducer batch
@@ -189,8 +209,8 @@ class Plan:
     def cache_key(self) -> tuple:
         """The static part of the compile-cache key (shape bucket is added
         by the counter)."""
-        return (self.method, self.n_stages, self.use_kernel, self.interpret,
-                self.balance, self.edge_batch, self.node_batch, self.block_size,
+        return (self.method, self.n_stages, self.use_kernel, self.balance,
+                self.edge_batch, self.node_batch, self.block_size,
                 self.window_epochs, self.state_layout, self.hub_slots,
                 self.tail_capacity, self.hub_threshold)
 
@@ -334,12 +354,13 @@ def hybrid_sizing(stats: GraphStats, res: Resources) -> HybridSizing | None:
 
 
 def backend_exec_flags(res: Resources) -> dict:
-    """The backend decision every executable plan carries: compiled Pallas
-    kernels on TPU, interpret-mode XLA elsewhere. One definition so the
+    """The backend decision every executable plan carries: the Pallas
+    kernels on TPU, the XLA formulation elsewhere. One definition so the
     planner's stream/resident branches and the counter's batch plan cannot
-    drift apart."""
-    return {"use_kernel": res.backend == "tpu",
-            "interpret": res.backend != "tpu"}
+    drift apart. Whether a kernel runs compiled or interpreted is not a plan
+    field: ``repro.utils.interpret_mode`` decides it from the live
+    backend."""
+    return {"use_kernel": res.backend == "tpu"}
 
 
 def plan(stats: GraphStats, resources: Resources | None = None, *,
@@ -365,7 +386,7 @@ def plan(stats: GraphStats, resources: Resources | None = None, *,
     executable. For concurrent stream serving, :func:`admit_session` is the
     budgeted variant that may answer "queue" instead of always planning.
     """
-    res = resources or Resources()
+    res = resources or Resources.detect()
     allowed = set(allow) if allow is not None else set(METHODS)
     unknown = allowed - set(METHODS)
     if unknown:
@@ -553,7 +574,7 @@ def admit_session(n_nodes: int, resources: Resources | None = None, *,
     with prefetch has its in-flight blocks paid for, so a full pipeline can
     never overcommit the device past what admission approved.
     """
-    res = resources or Resources()
+    res = resources or Resources.detect()
     remaining = max(res.memory_bytes - bytes_in_use, 0)
     stats = GraphStats(n_nodes=n_nodes, n_edges=0, replication_factor=0,
                        max_degree=0, max_fwd_degree=0, edges_in_memory=False)

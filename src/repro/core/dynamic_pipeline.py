@@ -23,8 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.utils import shard_map_compat as _shard_map
-
 
 def ring_stream(
     process: Callable[[Any, Any, jax.Array], Any],
@@ -105,11 +103,12 @@ class DynamicPipeline:
             out = spec.finalize(state)
             return jax.tree.map(lambda x: jax.lax.psum(x, ax), out)
 
-        sharded = _shard_map(
+        sharded = jax.shard_map(
             stage_fn,
             mesh=self.mesh,
             in_specs=(P(ax), P(ax)),
             out_specs=P(),
+            check_vma=False,
         )
         return sharded(resident, stream)
 
@@ -173,11 +172,12 @@ class ShardedStateStream:
                 state_local, carry = step_fn(state_local, carry, block)
                 return jax.tree.map(lambda x: x[None], state_local), carry
 
-            sharded = _shard_map(
+            sharded = jax.shard_map(
                 stage_fn,
                 mesh=self.mesh,
                 in_specs=(P(ax), P(), P()),
                 out_specs=(P(ax), P()),
+                check_vma=False,
             )
             self._jit_cache[step_fn] = jax.jit(sharded)
         return self._jit_cache[step_fn]

@@ -92,16 +92,29 @@ def init_state(n_nodes: int) -> dict:
     }
 
 
-def init_sharded_state(n_nodes: int, n_stages: int) -> dict:
+def _stage_zeros(shape: tuple, mesh=None) -> jax.Array:
+    """A zero uint32 array whose leading axis is the stage axis: on ``mesh``
+    it is allocated as one shard per device along the mesh's first axis —
+    never whole on one chip first — and on one device otherwise."""
+    if mesh is None:
+        return jnp.zeros(shape, jnp.uint32)
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    sharding = NamedSharding(mesh, P(mesh.axis_names[0]))
+    return jax.jit(lambda: jnp.zeros(shape, jnp.uint32),
+                   out_shardings=sharding)()
+
+
+def init_sharded_state(n_nodes: int, n_stages: int, mesh=None) -> dict:
     """Column-sharded state: stage s owns words [s·Ws, (s+1)·Ws) of every
     row — n·Ws·4 ≈ n²/8/S bytes PER STAGE (S·n·Ws·4 total when the sharding
-    is host-emulated on one device). The trailing pad words (W rounded up to
-    S·Ws) map to no node and stay zero forever. Allocation only; traces
-    nothing."""
+    is host-emulated on one device; ``mesh`` places each stage's shard on
+    its own device). The trailing pad words (W rounded up to S·Ws) map to
+    no node and stay zero forever. Allocation only."""
     w = -(-n_nodes // 32)
     ws = -(-w // n_stages)
     return {
-        "adj": jnp.zeros((n_stages, n_nodes, ws), jnp.uint32),
+        "adj": _stage_zeros((n_stages, n_nodes, ws), mesh),
         "count": jnp.zeros((), count_dtype()),
     }
 
@@ -131,18 +144,18 @@ def init_windowed_state(n_nodes: int, window_epochs: int) -> dict:
 
 
 def init_windowed_sharded_state(n_nodes: int, window_epochs: int,
-                                n_stages: int) -> dict:
+                                n_stages: int, mesh=None) -> dict:
     """Ring-sharded windowed state: ``init_windowed_state`` with every epoch
     bitset column-sharded over S stages exactly like ``init_sharded_state``
     — ``E·n·Ws·4 ≈ E·n²/8/S`` bytes per stage (all S shards on one device
-    when host-emulated). ``counts``/``head`` are replicated scalars.
-    Allocation only; traces nothing."""
+    when host-emulated, one per device on ``mesh``). ``counts``/``head`` are
+    replicated scalars. Allocation only."""
     if window_epochs < 1:
         raise ValueError(f"window_epochs must be >= 1, got {window_epochs}")
     w = -(-n_nodes // 32)
     ws = -(-w // n_stages)
     return {
-        "epochs": jnp.zeros((n_stages, window_epochs, n_nodes, ws), jnp.uint32),
+        "epochs": _stage_zeros((n_stages, window_epochs, n_nodes, ws), mesh),
         "counts": jnp.zeros((window_epochs,), count_dtype()),
         "head": jnp.zeros((), jnp.int32),
     }
@@ -189,13 +202,23 @@ def snapshot_state(state: dict) -> dict:
     return {k: np.asarray(v) for k, v in state.items()}
 
 
-def restore_state(snap: dict) -> dict:
+_STAGE_KEYS = ("adj", "epochs")  # state arrays whose leading axis is the stage
+
+
+def restore_state(snap: dict, mesh=None) -> dict:
     """Device rehydration of a :func:`snapshot_state` copy — the restore
-    half. ``jnp.asarray`` preserves dtype and bits exactly, so a restored
-    stream continues bit-identically to one that was never interrupted.
-    Traces nothing (a jitted ingest step re-shards the arrays on first use
-    when the session is mesh-sharded)."""
-    return {k: jnp.asarray(v) for k, v in snap.items()}
+    half. Dtype and bits are preserved exactly, so a restored stream
+    continues bit-identically to one that was never interrupted. On
+    ``mesh`` the stage-axis arrays land one shard per device, as
+    ``init_sharded_state`` allocates them, so the session's compiled step
+    takes them as they are. Traces nothing."""
+    if mesh is None:
+        return {k: jnp.asarray(v) for k, v in snap.items()}
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    stage = NamedSharding(mesh, P(mesh.axis_names[0]))
+    return {k: jax.device_put(v, stage) if k in _STAGE_KEYS else jnp.asarray(v)
+            for k, v in snap.items()}
 
 
 def state_nbytes(state: dict) -> int:
@@ -287,8 +310,8 @@ def _phantom_edges(lo: jax.Array, hi: jax.Array, live: jax.Array, n: int) -> jax
 
 
 def _stage_update(adj_s: jax.Array, lo: jax.Array, hi: jax.Array,
-                  live: jax.Array, off, *, use_kernel: bool = False,
-                  interpret: bool = True) -> tuple[jax.Array, jax.Array]:
+                  live: jax.Array, off, *,
+                  use_kernel: bool = False) -> tuple[jax.Array, jax.Array]:
     """One stage's share of the two-phase block ingest.
 
     Returns (new word shard, (pre, mixed, dd) partials). The caller combines
@@ -313,12 +336,11 @@ def _stage_update(adj_s: jax.Array, lo: jax.Array, hi: jax.Array,
         from repro.kernels.bitset_count.ops import bitset_edge_count, bitset_pair_count
 
         ek = _phantom_edges(lo, hi, live, n)
-        pre = bitset_edge_count(adj_s, ek, interpret=interpret).astype(count_dtype())
+        pre = bitset_edge_count(adj_s, ek)
         if 2 * table_bytes <= _MASK_VMEM_BUDGET:  # pair kernel holds two tables
-            mixed = (bitset_pair_count(adj_s, delta, ek, interpret=interpret)
-                     + bitset_pair_count(delta, adj_s, ek, interpret=interpret)
-                     ).astype(count_dtype())
-            dd = bitset_edge_count(delta, ek, interpret=interpret).astype(count_dtype())
+            mixed = (bitset_pair_count(adj_s, delta, ek)
+                     + bitset_pair_count(delta, adj_s, ek))
+            dd = bitset_edge_count(delta, ek)
         else:
             mixed = masked_sum(au & dv) + masked_sum(du & av)
             dd = masked_sum(du & dv)
@@ -357,7 +379,7 @@ def _age_cum(epochs_s: jax.Array, head) -> jax.Array:
 def _windowed_stage_update(epochs_s: jax.Array, cum: jax.Array,
                            lo: jax.Array, hi: jax.Array,
                            live: jax.Array, off, head, *,
-                           use_kernel: bool = False, interpret: bool = True
+                           use_kernel: bool = False
                            ) -> tuple[jax.Array, jax.Array]:
     """One stage's share of the windowed two-phase block ingest.
 
@@ -395,18 +417,16 @@ def _windowed_stage_update(epochs_s: jax.Array, cum: jax.Array,
         pair_ok = 2 * table_bytes <= _MASK_VMEM_BUDGET
         ps, ms = [], []
         for t in range(n_epochs):  # the unbounded kernels, once per epoch age
-            ps.append(bitset_edge_count(cum[t], ek,
-                                        interpret=interpret).astype(count_dtype()))
+            ps.append(bitset_edge_count(cum[t], ek))
             if pair_ok:
-                ms.append((bitset_pair_count(cum[t], delta, ek, interpret=interpret)
-                           + bitset_pair_count(delta, cum[t], ek, interpret=interpret)
-                           ).astype(count_dtype()))
+                ms.append(bitset_pair_count(cum[t], delta, ek)
+                          + bitset_pair_count(delta, cum[t], ek))
             else:
                 cu, cv = cum[t][glo], cum[t][ghi]
                 ms.append(masked_sum(cu & dv) + masked_sum(du & cv))
         p_terms = jnp.stack(ps)
         m_terms = jnp.stack(ms)
-        dd = bitset_edge_count(delta, ek, interpret=interpret).astype(count_dtype())
+        dd = bitset_edge_count(delta, ek)
     else:
         cu, cv = cum[:, glo], cum[:, ghi]       # (E, B, ws)
         p_terms = masked_sum(cu & cv)           # (E,)
@@ -443,8 +463,7 @@ def window_count(state: dict):
 
 
 def _ingest_block_impl(state: dict, edges: jax.Array, *,
-                       use_kernel: bool = False,
-                       interpret: bool = True) -> dict:
+                       use_kernel: bool = False) -> dict:
     """Fold one (B, 2) int32 edge block (phantom rows: id >= n_nodes) with the
     two-phase blocked ingest. Duplicate edges are ignored (the paper's
     simple-graph precondition); self-loops contribute nothing.
@@ -464,12 +483,11 @@ def _ingest_block_impl(state: dict, edges: jax.Array, *,
     n = adj.shape[0]
     keep, lo, hi = _canonical_live(edges, n)
     live = keep & (_stage_seen(adj, lo, hi, 0) == 0)
-    adj, terms = _stage_update(adj, lo, hi, live, 0,
-                               use_kernel=use_kernel, interpret=interpret)
+    adj, terms = _stage_update(adj, lo, hi, live, 0, use_kernel=use_kernel)
     return {"adj": adj, "count": _combine(state["count"], terms)}
 
 
-_INGEST_STATICS = ("use_kernel", "interpret")
+_INGEST_STATICS = ("use_kernel",)
 ingest_block = partial(jax.jit, static_argnames=_INGEST_STATICS)(
     _ingest_block_impl)
 ingest_block_donated = partial(jax.jit, static_argnames=_INGEST_STATICS,
@@ -504,7 +522,7 @@ ingest_block_sharded_donated = jax.jit(_ingest_block_sharded_impl,
 
 @lru_cache(maxsize=32)
 def make_mesh_ingest(mesh, axis_name: str | None = None, *,
-                     use_kernel: bool = False, interpret: bool = True):
+                     use_kernel: bool = False):
     """Jitted ring-sharded ingest step over a real device mesh: the state's
     stage axis is laid out along ``axis_name`` (one word shard per device)
     via ``dynamic_pipeline.ShardedStateStream``; ``seen`` and the
@@ -527,7 +545,7 @@ def make_mesh_ingest(mesh, axis_name: str | None = None, *,
         seen = jax.lax.psum(_stage_seen(adj_s, lo, hi, off), ax)
         live = keep & (seen == 0)
         adj_s, terms = _stage_update(adj_s, lo, hi, live, off,
-                                     use_kernel=use_kernel, interpret=interpret)
+                                     use_kernel=use_kernel)
         return adj_s, _combine(carry, jax.lax.psum(terms, ax))
 
     fn = runtime.jit_step(step)
@@ -543,8 +561,7 @@ def make_mesh_ingest(mesh, axis_name: str | None = None, *,
 # Sliding-window ingest: the epoch ring (dense / emulated-sharded / mesh)
 # --------------------------------------------------------------------------
 def _ingest_block_windowed_impl(state: dict, edges: jax.Array, *,
-                                use_kernel: bool = False,
-                                interpret: bool = True) -> dict:
+                                use_kernel: bool = False) -> dict:
     """Fold one (B, 2) int32 edge block into the CURRENT epoch of a windowed
     state (``init_windowed_state``; phantom rows: id >= n_nodes).
 
@@ -568,8 +585,7 @@ def _ingest_block_windowed_impl(state: dict, edges: jax.Array, *,
     cum = _age_cum(epochs, state["head"])  # cum[-1] = live adjacency
     live = keep & (_stage_seen(cum[-1], lo, hi, 0) == 0)
     epochs, terms = _windowed_stage_update(
-        epochs, cum, lo, hi, live, 0, state["head"],
-        use_kernel=use_kernel, interpret=interpret)
+        epochs, cum, lo, hi, live, 0, state["head"], use_kernel=use_kernel)
     return {"epochs": epochs,
             "counts": _windowed_combine(state["counts"], terms, state["head"]),
             "head": state["head"]}
@@ -615,7 +631,7 @@ ingest_block_windowed_sharded_donated = jax.jit(
 
 @lru_cache(maxsize=32)
 def make_mesh_ingest_windowed(mesh, axis_name: str | None = None, *,
-                              use_kernel: bool = False, interpret: bool = True):
+                              use_kernel: bool = False):
     """Jitted ring-sharded WINDOWED ingest step over a real device mesh: the
     epoch ring's stage axis is laid out along ``axis_name`` (E·n²/8/S bytes
     per device) via the same ``dynamic_pipeline.ShardedStateStream`` runtime
@@ -640,8 +656,7 @@ def make_mesh_ingest_windowed(mesh, axis_name: str | None = None, *,
         seen = jax.lax.psum(_stage_seen(cum[-1], lo, hi, off), ax)
         live = keep & (seen == 0)
         epochs_s, terms = _windowed_stage_update(
-            epochs_s, cum, lo, hi, live, off, head,
-            use_kernel=use_kernel, interpret=interpret)
+            epochs_s, cum, lo, hi, live, off, head, use_kernel=use_kernel)
         counts = _windowed_combine(counts, jax.lax.psum(terms, ax), head)
         return epochs_s, (counts, head)
 
@@ -688,8 +703,7 @@ def expire_epoch(state: dict) -> dict:
 
 def count_windowed_stream(n_nodes: int, epochs, window_epochs: int, *,
                           block_size: int | None = None, n_stages: int = 1,
-                          mesh=None, use_kernel: bool = False,
-                          interpret: bool = True) -> int:
+                          mesh=None, use_kernel: bool = False) -> int:
     """Consume an iterable of EPOCHS — each an iterable of (B, 2) numpy edge
     blocks — and return the triangle count of the final window (the last
     ``window_epochs`` epochs), host-synced. The core-level twin of
@@ -703,16 +717,16 @@ def count_windowed_stream(n_nodes: int, epochs, window_epochs: int, *,
     epoch bitset (E·n²/8/S bytes per stage on ``mesh`` when its size
     matches, else host-emulated)."""
     if n_stages > 1:
-        state = init_windowed_sharded_state(n_nodes, window_epochs, n_stages)
-        if mesh is not None and mesh.devices.size == n_stages:
-            step = make_mesh_ingest_windowed(mesh, use_kernel=use_kernel,
-                                             interpret=interpret)
+        on_mesh = mesh is not None and mesh.devices.size == n_stages
+        state = init_windowed_sharded_state(n_nodes, window_epochs, n_stages,
+                                            mesh=mesh if on_mesh else None)
+        if on_mesh:
+            step = make_mesh_ingest_windowed(mesh, use_kernel=use_kernel)
         else:
             step = ingest_block_windowed_sharded
     else:
         state = init_windowed_state(n_nodes, window_epochs)
-        step = partial(ingest_block_windowed, use_kernel=use_kernel,
-                       interpret=interpret)
+        step = partial(ingest_block_windowed, use_kernel=use_kernel)
     buf = BlockBuffer(n_nodes, block_size)
 
     def _drain(blocks):
@@ -1261,8 +1275,7 @@ def padded_blocks(blocks, n_nodes: int, block_size: int | None = None):
 
 
 def count_stream(n_nodes: int, blocks, *, block_size: int | None = None,
-                 n_stages: int = 1, mesh=None, use_kernel: bool = False,
-                 interpret: bool = True) -> int:
+                 n_stages: int = 1, mesh=None, use_kernel: bool = False) -> int:
     """Consume an iterable of (B, 2) numpy edge blocks; returns the exact
     triangle count without ever materializing the full edge list. Blocks are
     coalesced/padded to one fixed shape (see ``padded_blocks``) so the whole
@@ -1274,14 +1287,16 @@ def count_stream(n_nodes: int, blocks, *, block_size: int | None = None,
     emulated on host. ``use_kernel`` routes the phase-1 closure sweep through
     ``kernels/bitset_count`` where the state fits its VMEM/SMEM budgets."""
     if n_stages > 1:
-        state = init_sharded_state(n_nodes, n_stages)
-        if mesh is not None and mesh.devices.size == n_stages:
-            step = make_mesh_ingest(mesh, use_kernel=use_kernel, interpret=interpret)
+        on_mesh = mesh is not None and mesh.devices.size == n_stages
+        state = init_sharded_state(n_nodes, n_stages,
+                                   mesh=mesh if on_mesh else None)
+        if on_mesh:
+            step = make_mesh_ingest(mesh, use_kernel=use_kernel)
         else:
             step = ingest_block_sharded
     else:
         state = init_state(n_nodes)
-        step = partial(ingest_block, use_kernel=use_kernel, interpret=interpret)
+        step = partial(ingest_block, use_kernel=use_kernel)
     for block in padded_blocks(blocks, n_nodes, block_size):
         state = step(state, block)
     return int(state["count"])

@@ -32,7 +32,7 @@ from repro.graphs.formats import Graph
 # --------------------------------------------------------------------------
 # Dense single-device path
 # --------------------------------------------------------------------------
-def count_triangles_dense(u: jax.Array, *, use_kernel: bool = False, interpret: bool = True) -> jax.Array:
+def count_triangles_dense(u: jax.Array, *, use_kernel: bool = False) -> jax.Array:
     """sum(U ⊙ (U @ U)) — U strictly upper triangular 0/1, any float dtype.
 
     The matmul is exact in f32 (entries ≤ n < 2²⁴) but the REDUCTION must be
@@ -42,7 +42,7 @@ def count_triangles_dense(u: jax.Array, *, use_kernel: bool = False, interpret: 
     if use_kernel:
         from repro.kernels.triangle_count.ops import triangle_count as tc_kernel
 
-        return tc_kernel(u, interpret=interpret)
+        return tc_kernel(u)
     prod = jax.lax.dot(u, u, preferred_element_type=jnp.float32)
     masked = (prod * u.astype(jnp.float32)).astype(jnp.int32)
     return jnp.sum(masked, dtype=count_dtype())
@@ -86,7 +86,7 @@ def count_triangles_sparse(
 # Ring (dense row-block streaming) — the distributed dynamic pipeline
 # --------------------------------------------------------------------------
 @lru_cache(maxsize=None)
-def dense_ring_spec(rows_per_stage: int, *, use_kernel: bool = False, interpret: bool = True) -> FilterSpec:
+def dense_ring_spec(rows_per_stage: int, *, use_kernel: bool = False) -> FilterSpec:
     """FilterSpec for the dense ring. Resident = this stage's row block U_s
     (R, n_pad); streamed blocks are the row blocks of every stage; block from
     stage k covers ranks [k*R, (k+1)*R) (the k-slice of the contraction).
@@ -106,7 +106,7 @@ def dense_ring_spec(rows_per_stage: int, *, use_kernel: bool = False, interpret:
         if use_kernel:
             from repro.kernels.triangle_count.ops import masked_matmul_sum
 
-            partial_ = masked_matmul_sum(cols, u_k, u_s, interpret=interpret)
+            partial_ = masked_matmul_sum(cols, u_k, u_s)
         else:
             wide = jnp.int32 if jnp.issubdtype(u_s.dtype, jnp.integer) else jnp.float32
             prod = jax.lax.dot(cols, u_k, preferred_element_type=wide)
@@ -147,7 +147,6 @@ def count_triangles_ring(
     n_stages: int | None = None,
     balance: bool = True,
     use_kernel: bool = False,
-    interpret: bool = True,
     sequential: bool = False,
     dtype=np.uint8,
 ) -> int:
@@ -158,7 +157,7 @@ def count_triangles_ring(
         n_stages = mesh.devices.size
     n_stages = n_stages or 1
     part, blocks = build_dense_ring_operands(g, n_stages, balance=balance, dtype=dtype)
-    spec = dense_ring_spec(part.rows_per_stage, use_kernel=use_kernel, interpret=interpret)
+    spec = dense_ring_spec(part.rows_per_stage, use_kernel=use_kernel)
     blocks = jnp.asarray(blocks)
     if sequential or mesh is None or mesh.devices.size == 1:
         out = run_sequential(spec, blocks, blocks, n_stages)
@@ -175,8 +174,35 @@ def count_triangles_ring(
 # scalar-prefetch operand in SMEM — both must fit or we fall back to pure JAX.
 _MASK_VMEM_BUDGET = 8 * 1024 * 1024
 _EDGE_SMEM_BUDGET = 256 * 1024
+# The XLA closure sweep gathers two (B, W) uint32 row tables; it walks the
+# edge block in chunks that keep them near this many bytes, whatever B is.
+_XLA_GATHER_BUDGET = 64 * 1024 * 1024
+
+
+def _bitset_closures_xla(mask: jax.Array, edge_block: jax.Array) -> jax.Array:
+    """Σ_e popcount(mask[u_e] & mask[v_e]) over a (B, 2) edge block
+    (phantom rows: id >= n_pad), in ``count_dtype``. Halving stops at an
+    odd chunk; the counter's power-of-two blocks always reach the budget."""
+    n_pad, w = mask.shape
+    b = edge_block.shape[0]
+    chunk = max(b, 1)
+    while chunk % 2 == 0 and chunk > 128 and 8 * chunk * w > _XLA_GATHER_BUDGET:
+        chunk //= 2
+
+    def closures(acc, eb):
+        u = jnp.minimum(eb[:, 0], n_pad - 1)
+        v = jnp.minimum(eb[:, 1], n_pad - 1)
+        pc = jax.lax.population_count(jnp.bitwise_and(mask[u], mask[v])).sum(axis=-1)
+        return acc + jnp.sum(jnp.where(eb[:, 0] < n_pad, pc, 0),
+                             dtype=count_dtype()), None
+
+    acc, _ = jax.lax.scan(closures, jnp.zeros((), count_dtype()),
+                          edge_block.reshape(b // chunk, chunk, 2))
+    return acc
+
+
 @lru_cache(maxsize=None)
-def bitset_ring_spec(*, use_kernel: bool = False, interpret: bool = True) -> FilterSpec:
+def bitset_ring_spec(*, use_kernel: bool = False) -> FilterSpec:
     """Resident = (n_pad, W) uint32 membership bitmask over this stage's
     responsible ranks; streamed = (B, 2) int32 edge blocks in rank space.
 
@@ -198,15 +224,9 @@ def bitset_ring_spec(*, use_kernel: bool = False, interpret: bool = True) -> Fil
                 and edge_block.size * 4 <= _EDGE_SMEM_BUDGET):
             from repro.kernels.bitset_count.ops import bitset_edge_count
 
-            partial_ = bitset_edge_count(mask, edge_block, interpret=interpret)
+            partial_ = bitset_edge_count(mask, edge_block)
         else:
-            n_pad = mask.shape[0]
-            u = jnp.minimum(edge_block[:, 0], n_pad - 1)
-            v = jnp.minimum(edge_block[:, 1], n_pad - 1)
-            valid = edge_block[:, 0] < n_pad
-            both = jnp.bitwise_and(mask[u], mask[v])
-            pc = jax.lax.population_count(both).sum(axis=-1)
-            partial_ = jnp.sum(jnp.where(valid, pc, 0), dtype=count_dtype())
+            partial_ = _bitset_closures_xla(mask, edge_block)
         return (mask, acc + partial_.astype(count_dtype()))
 
     def finalize(state):
@@ -244,13 +264,13 @@ def build_bitset_ring_operands(
 
 def count_triangles_bitset_ring(
     g: Graph, *, mesh=None, n_stages: int | None = None, balance: bool = True,
-    use_kernel: bool = False, interpret: bool = True, sequential: bool = False
+    use_kernel: bool = False, sequential: bool = False
 ) -> int:
     if mesh is not None and n_stages is None:
         n_stages = mesh.devices.size
     n_stages = n_stages or 1
     part, masks, edges = build_bitset_ring_operands(g, n_stages, balance=balance)
-    spec = bitset_ring_spec(use_kernel=use_kernel, interpret=interpret)
+    spec = bitset_ring_spec(use_kernel=use_kernel)
     masks, edges = jnp.asarray(masks), jnp.asarray(edges)
     if sequential or mesh is None or mesh.devices.size == 1:
         out = run_sequential(spec, masks, edges, n_stages)

@@ -7,13 +7,13 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.embedding_bag.embedding_bag import embedding_bag_kernel
+from repro.utils import interpret_mode
 
 
 @partial(jax.jit, static_argnames=("interpret",))
 def embedding_bag(table: jax.Array, indices: jax.Array, *, interpret: bool | None = None) -> jax.Array:
     """Sum-mode EmbeddingBag. table: (V, D); indices: (B, L) with sentinel >= V
     rows meaning padding. Returns (B, D) in the table dtype."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = interpret_mode(interpret)
     out = embedding_bag_kernel(table, indices.astype(jnp.int32), interpret=interpret)
     return out.astype(table.dtype)

@@ -1,4 +1,4 @@
-"""Public flash-attention wrapper: pads sequence, picks interpret mode."""
+"""Public flash-attention wrapper: pads the sequence to the block size."""
 from __future__ import annotations
 
 from functools import partial
@@ -7,6 +7,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.flash_attention.flash_attention import flash_attention_kernel
+from repro.utils import interpret_mode
 
 
 @partial(jax.jit, static_argnames=("causal", "block_q", "block_k", "interpret"))
@@ -21,8 +22,7 @@ def flash_attention(
     interpret: bool | None = None,
 ) -> jax.Array:
     """Causal GQA attention. q: (B, Hq, S, D); k, v: (B, Hkv, S, D)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = interpret_mode(interpret)
     b, hq, s, d = q.shape
     blk = max(block_q, block_k)
     pad = (-s) % blk

@@ -11,10 +11,8 @@ from repro.kernels.triangle_count.triangle_count import (
     masked_matmul_sum_kernel,
     triangle_count_live_kernel,
 )
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+from repro.kernels import note_kernel_trace
+from repro.utils import count_dtype, interpret_mode
 
 
 # Scalar-prefetch operands live in SMEM; cap the live-triple table well below
@@ -42,13 +40,14 @@ def masked_matmul_sum(
     upper_triangular: bool = False,
     interpret: bool | None = None,
 ) -> jax.Array:
-    """sum((A @ B) ⊙ M). Pads to block multiples (zero pad is count-neutral)."""
-    if interpret is None:
-        interpret = not _on_tpu()
+    """sum((A @ B) ⊙ M) in ``count_dtype`` for 0/1 operands. Pads to block
+    multiples (zero pad is count-neutral)."""
+    interpret = interpret_mode(interpret)
+    note_kernel_trace("masked_matmul_sum", interpret)
     a = _pad2(a, block_m, block_k)
     b = _pad2(b, block_k, block_n)
     m = _pad2(m, block_m, block_n)
-    return masked_matmul_sum_kernel(
+    partials = masked_matmul_sum_kernel(
         a,
         b,
         m,
@@ -58,6 +57,7 @@ def masked_matmul_sum(
         upper_triangular=upper_triangular,
         interpret=interpret,
     )
+    return jnp.sum(partials, dtype=count_dtype())
 
 
 @partial(jax.jit, static_argnames=("block", "interpret", "live_grid"))
@@ -76,12 +76,13 @@ def triangle_count(u: jax.Array, *, block: int = 128, interpret: bool | None = N
     block 128, i.e. n ≤ ~7k — beyond that the count is ring-partitioned
     anyway).
     """
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = interpret_mode(interpret)
     u = _pad2(u, block, block)
     nb = u.shape[0] // block
     if live_grid and live_grid_size(nb) * 12 > _SMEM_TABLE_BUDGET:
         live_grid = False
+    note_kernel_trace("triangle_count_live" if live_grid
+                      else "triangle_count_full_grid", interpret)
     if live_grid:
         out = triangle_count_live_kernel(u, block=block, interpret=interpret)
     else:
@@ -89,9 +90,7 @@ def triangle_count(u: jax.Array, *, block: int = 128, interpret: bool | None = N
             u, u, u, block_m=block, block_n=block, block_k=block,
             upper_triangular=True, interpret=interpret,
         )
-    from repro.utils import count_dtype
-
-    return out.astype(count_dtype())
+    return jnp.sum(out, dtype=count_dtype())
 
 
 def triangle_count_grid_steps(n: int, *, block: int = 128, live_grid: bool = True) -> int:

@@ -2,8 +2,9 @@
 
 This is the counting phase of the dynamic pipeline on the MXU. A is (R, K),
 B is (K, N), M is (R, N); all blocks are VMEM-resident tiles, the contraction
-accumulates into an f32 VMEM scratch, and the masked reduction folds into a
-single (1, 1) output block that stays resident across the whole grid.
+accumulates into an f32 VMEM scratch, and the masked reduction folds into an
+(8, block_n) int32 output block that stays resident across the whole grid
+(the wrapper sums it).
 
 Two grid strategies:
 
@@ -39,10 +40,24 @@ from jax.experimental.pallas import tpu as pltpu
 
 def _widen(x: jax.Array) -> jax.Array:
     """MXU operand dtype: integer 0/1 adjacency (uint8 ring streaming) is
-    exact in f32 for per-block contractions (entries ≤ block_k < 2^24)."""
+    exact in f32 for per-block contractions (entries ≤ block_k < 2^24).
+    Mosaic has no direct uint8 -> f32 cast, so it goes through int32."""
     if jnp.issubdtype(x.dtype, jnp.integer):
-        return x.astype(jnp.float32)
+        return x.astype(jnp.int32).astype(jnp.float32)
     return x
+
+
+def _fold_block(out_ref, acc: jax.Array, m: jax.Array) -> None:
+    """Add the masked block sum Σ acc ⊙ M into the (8, bn) int32 output.
+
+    Each product entry is an exact integer in f32 (≤ n < 2^24), so it is
+    cast to int32 BEFORE any sum (an f32 block sum loses exactness past
+    2^24); the rows fold by sublane groups, which keeps the accumulator a
+    vector block — Mosaic stores no scalars to VMEM. The wrapper sums the
+    (8, bn) partials."""
+    prod = (acc * _widen(m)).astype(jnp.int32)
+    bm, bn = prod.shape
+    out_ref[...] += prod.reshape(bm // 8, 8, bn).sum(axis=0, dtype=jnp.int32)
 
 
 def _kernel(a_ref, b_ref, m_ref, out_ref, acc_ref, *, n_k: int, upper_triangular: bool):
@@ -69,11 +84,7 @@ def _kernel(a_ref, b_ref, m_ref, out_ref, acc_ref, *, n_k: int, upper_triangular
 
     @pl.when(k == n_k - 1)
     def _reduce():
-        # per-block sum is exact in f32 (≤ block_m·block_n·block_k < 2^24);
-        # the RUNNING total accumulates in int32 — f32 accumulation loses
-        # exactness past 2^24 total
-        blk = jnp.sum(acc_ref[...] * _widen(m_ref[...]))
-        out_ref[0, 0] += blk.astype(jnp.int32)
+        _fold_block(out_ref, acc_ref[...], m_ref[...])
 
 
 def masked_matmul_sum_kernel(
@@ -89,7 +100,8 @@ def masked_matmul_sum_kernel(
 ) -> jax.Array:
     """sum((A @ B) ⊙ M) with (R, K) @ (K, N) against mask (R, N).
 
-    Shapes must be multiples of the block sizes (ops.py pads).
+    Shapes must be multiples of the block sizes (ops.py pads). Returns the
+    (8, block_n) int32 partials; the caller sums them.
     """
     R, K = a.shape
     K2, N = b.shape
@@ -104,12 +116,12 @@ def masked_matmul_sum_kernel(
             pl.BlockSpec((block_k, block_n), lambda i, j, k: (k, j)),
             pl.BlockSpec((block_m, block_n), lambda i, j, k: (i, j)),
         ],
-        out_specs=pl.BlockSpec((1, 1), lambda i, j, k: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, 1), jnp.int32),
+        out_specs=pl.BlockSpec((8, block_n), lambda i, j, k: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((8, block_n), jnp.int32),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
         interpret=interpret,
     )(a, b, m)
-    return out[0, 0]
+    return out
 
 
 # --------------------------------------------------------------------------
@@ -139,7 +151,7 @@ def live_grid_size(n_blocks: int) -> int:
 
 def _live_kernel(idx_ref, a_ref, b_ref, m_ref, out_ref, acc_ref):
     g = pl.program_id(0)
-    i, j, k = idx_ref[g, 0], idx_ref[g, 1], idx_ref[g, 2]
+    i, j, k = idx_ref[3 * g], idx_ref[3 * g + 1], idx_ref[3 * g + 2]
 
     @pl.when(g == 0)
     def _init_out():
@@ -155,8 +167,7 @@ def _live_kernel(idx_ref, a_ref, b_ref, m_ref, out_ref, acc_ref):
 
     @pl.when(k == j)  # last contraction step: fold the masked block sum
     def _reduce():
-        blk = jnp.sum(acc_ref[...] * _widen(m_ref[...]))
-        out_ref[0, 0] += blk.astype(jnp.int32)
+        _fold_block(out_ref, acc_ref[...], m_ref[...])
 
 
 def triangle_count_live_kernel(
@@ -168,30 +179,32 @@ def triangle_count_live_kernel(
     """sum(U ⊙ (U @ U)) over the compacted live grid.
 
     U must be square, strictly upper triangular, and padded to a multiple of
-    ``block`` (ops.py pads). The (n_live, 3) triple table is scalar-prefetched
+    ``block`` (ops.py pads). The flattened triple table is scalar-prefetched
     and drives every BlockSpec index map, so each grid step DMAs exactly the
-    three live tiles it needs.
+    three live tiles it needs. Returns the (8, block) int32 partials; the
+    caller sums them.
     """
     n, n2 = u.shape
     assert n == n2 and n % block == 0, u.shape
     nb = n // block
-    idx = jnp.asarray(live_grid_indices(nb))
+    # flattened (i, j, k) triples: a 1-D SMEM table is packed, where an
+    # (n_live, 3) one pads its minor dimension
+    idx = jnp.asarray(live_grid_indices(nb).reshape(-1))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(idx.shape[0],),
+        grid=(idx.shape[0] // 3,),
         in_specs=[
-            pl.BlockSpec((block, block), lambda g, t: (t[g, 0], t[g, 2])),  # A(i, k)
-            pl.BlockSpec((block, block), lambda g, t: (t[g, 2], t[g, 1])),  # B(k, j)
-            pl.BlockSpec((block, block), lambda g, t: (t[g, 0], t[g, 1])),  # M(i, j)
+            pl.BlockSpec((block, block), lambda g, t: (t[3 * g], t[3 * g + 2])),      # A(i, k)
+            pl.BlockSpec((block, block), lambda g, t: (t[3 * g + 2], t[3 * g + 1])),  # B(k, j)
+            pl.BlockSpec((block, block), lambda g, t: (t[3 * g], t[3 * g + 1])),      # M(i, j)
         ],
-        out_specs=pl.BlockSpec((1, 1), lambda g, t: (0, 0)),
+        out_specs=pl.BlockSpec((8, block), lambda g, t: (0, 0)),
         scratch_shapes=[pltpu.VMEM((block, block), jnp.float32)],
     )
-    out = pl.pallas_call(
+    return pl.pallas_call(
         _live_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((1, 1), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((8, block), jnp.int32),
         interpret=interpret,
     )(idx, u, u, u)
-    return out[0, 0]

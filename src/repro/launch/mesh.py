@@ -33,10 +33,16 @@ def make_ring_mesh(n_stages: int | None = None) -> Mesh:
 
     On the production mesh the DP ring is the flattened (data, model) axes of
     a pod; here we build it directly over the first ``n_stages`` devices.
+    Asking for more stages than there are devices raises: a narrower mesh
+    would quietly run the ring on fewer chips than the caller sized for.
     """
     devs = jax.devices()
     if n_stages is None:
         n_stages = len(devs)
+    if n_stages > len(devs):
+        raise ValueError(
+            f"ring mesh of {n_stages} stages needs {n_stages} devices; "
+            f"{len(devs)} {devs[0].platform} device(s) exist")
     return Mesh(np.asarray(devs[:n_stages]), ("stage",))
 
 

@@ -113,8 +113,6 @@ def local_scatter_sum(msg: jax.Array, dst: jax.Array, n_loc: int,
 
         return jax.vmap(one)(msg, local)
 
-    from repro.utils import shard_map_compat as shard_map
-
     axes = tuple(mesh.axis_names)
 
     def body(m, d_):
@@ -124,9 +122,9 @@ def local_scatter_sum(msg: jax.Array, dst: jax.Array, n_loc: int,
         out = jax.ops.segment_sum(m[0], local, num_segments=n_loc + 1)[:n_loc]
         return out[None]
 
-    return shard_map(body, mesh=mesh,
-                     in_specs=(P(axes, None, None), P(axes, None)),
-                     out_specs=P(axes, None, None))(msg, dst)
+    return jax.shard_map(body, mesh=mesh, check_vma=False,
+                         in_specs=(P(axes, None, None), P(axes, None)),
+                         out_specs=P(axes, None, None))(msg, dst)
 
 
 def local_take(arr: jax.Array, idx: jax.Array, mesh: Mesh | None = None) -> jax.Array:
@@ -137,16 +135,14 @@ def local_take(arr: jax.Array, idx: jax.Array, mesh: Mesh | None = None) -> jax.
     if mesh is None:
         return jax.vmap(lambda a, i: a[i])(arr, idx)
 
-    from repro.utils import shard_map_compat as shard_map
-
     axes = tuple(mesh.axis_names)
 
     def body(a, i):
         return a[0][i[0]][None]
 
-    return shard_map(body, mesh=mesh,
-                     in_specs=(P(axes, None, None), P(axes, None)),
-                     out_specs=P(axes, None, None))(arr, idx)
+    return jax.shard_map(body, mesh=mesh, check_vma=False,
+                         in_specs=(P(axes, None, None), P(axes, None)),
+                         out_specs=P(axes, None, None))(arr, idx)
 
 
 def local_segment_sum(vals: jax.Array, ids: jax.Array, num: int,
@@ -156,16 +152,14 @@ def local_segment_sum(vals: jax.Array, ids: jax.Array, num: int,
     if mesh is None:
         return jax.vmap(lambda v, i: jax.ops.segment_sum(v, i, num_segments=num))(vals, ids)
 
-    from repro.utils import shard_map_compat as shard_map
-
     axes = tuple(mesh.axis_names)
 
     def body(v, i):
         return jax.ops.segment_sum(v[0], i[0], num_segments=num)[None]
 
-    return shard_map(body, mesh=mesh,
-                     in_specs=(P(axes, None, None), P(axes, None)),
-                     out_specs=P(axes, None, None))(vals, ids)
+    return jax.shard_map(body, mesh=mesh, check_vma=False,
+                         in_specs=(P(axes, None, None), P(axes, None)),
+                         out_specs=P(axes, None, None))(vals, ids)
 
 
 def _reshape_edges(edges: jax.Array, n_dev: int) -> jax.Array:
@@ -177,15 +171,14 @@ def replicate_rows(x: jax.Array, mesh: Mesh) -> jax.Array:
     Unlike a replicated with_sharding_constraint, this cannot leak a
     'replicated' sharding choice back into the producer (measured: the layer
     scan's h carry stack became a replicated 21 GiB/device buffer)."""
-    from repro.utils import shard_map_compat as shard_map
 
     axes = tuple(mesh.axis_names)
 
     def body(xl):
         return jax.lax.all_gather(xl, axes, axis=0, tiled=True)
 
-    return shard_map(body, mesh=mesh, in_specs=P(axes, None),
-                     out_specs=P(None, None))(x)
+    return jax.shard_map(body, mesh=mesh, check_vma=False,
+                         in_specs=P(axes, None), out_specs=P(None, None))(x)
 
 
 # ---------------------------------------------------------------------------

@@ -85,7 +85,6 @@ def moe_apply_ep(p: dict, cfg: LMConfig, x: jax.Array, *, mesh,
     the capacity bound as the straggler guard (tokens beyond capacity drop,
     GShard semantics). Static shapes throughout; exact when capacity_factor
     is generous (tests verify against moe_apply)."""
-    from repro.utils import shard_map_compat as shard_map
     from jax.sharding import PartitionSpec as P
 
     mo = cfg.moe
@@ -134,9 +133,10 @@ def moe_apply_ep(p: dict, cfg: LMConfig, x: jax.Array, *, mesh,
         aux = e * jnp.sum(density * prob)
         return out, aux
 
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         body,
         mesh=mesh,
+        check_vma=False,
         in_specs=(P(), P("model", None, None), P("model", None, None),
                   P("model", None, None), P(dpa, None)),
         out_specs=(P(dpa, None), P()),

@@ -35,7 +35,7 @@ import dataclasses
 import os
 import tempfile
 
-from repro.serve.cluster.client import WorkerClient
+from repro.serve.cluster.client import WorkerClient, host_tpu_chips
 from repro.serve.cluster.protocol import WorkerDied
 
 
@@ -58,11 +58,37 @@ class _Placed:
     journal_bytes: int = 0
 
 
+def _spawn_all(workers) -> list:
+    """Spawn the spec dicts among ``workers`` (one chip each on a TPU
+    host), reaping the ones already up if a later spawn fails."""
+    chips = host_tpu_chips()
+    specs = [w for w in workers if not isinstance(w, WorkerClient)]
+    if chips and len(specs) > chips:
+        raise ValueError(f"{len(specs)} workers asked for, but this host has "
+                         f"{chips} TPU chip(s) and a worker owns one")
+    out: list = []
+    try:
+        for w in workers:
+            if not isinstance(w, WorkerClient):
+                chip = {"chip": len(out)} if chips else {}
+                w = WorkerClient.spawn(**{**chip, **w})
+            out.append(w)
+    except BaseException:
+        for w in out:
+            w.kill()
+        raise
+    return out
+
+
 class ClusterRouter:
     """Route stream sessions across worker processes (see module doc).
 
     ``workers`` may be pre-spawned :class:`WorkerClient`\\ s or spec dicts
-    (``{"memory_bytes": ..., "devices": ...}``) spawned here.
+    (``{"memory_bytes": ..., "devices": ...}``) spawned here. On a TPU host
+    every spawned worker owns one chip: spec ``i`` is pinned to chip ``i``
+    unless it names its own ``chip``, and asking for more workers than the
+    host has chips raises before any is spawned. If one spawn fails, the
+    workers already spawned are killed before the error propagates.
     ``checkpoint_dir`` is the shared directory checkpoint files live in
     (a private temp dir by default); ``checkpoint_every_bytes`` makes the
     router auto-checkpoint a session whenever its replay journal grows
@@ -74,9 +100,7 @@ class ClusterRouter:
     def __init__(self, workers, *, checkpoint_dir: str | None = None,
                  checkpoint_every_bytes: int | None = 1 << 20,
                  journal_budget_bytes: int = 64 << 20):
-        self.workers: list[WorkerClient | None] = [
-            w if isinstance(w, WorkerClient) else WorkerClient.spawn(**w)
-            for w in workers]
+        self.workers: list[WorkerClient | None] = _spawn_all(workers)
         if not self.workers:
             raise ValueError("a cluster needs at least one worker")
         self._charged = [0] * len(self.workers)

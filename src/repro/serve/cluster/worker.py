@@ -7,11 +7,19 @@ prints ``WORKER_READY <port>`` on stdout (the spawn handshake
 router connection, and serves length-prefixed requests until the router
 sends ``shutdown`` or the connection drops.
 
-``--devices K`` (> 1) forces K host devices via ``XLA_FLAGS`` BEFORE jax
-is imported and builds the ring mesh over them — the same harness the
-mesh tests use — so a cluster can mix meshed workers (per-stage n²/8/S
+The worker plans against the device it finds (``Resources.detect``:
+backend and ``bytes_limit`` from JAX); ``--memory-bytes`` overrides the
+budget it advertises. On a TPU host a worker owns exactly one chip:
+``--chip I`` names the chip its spawner pinned it to
+(``client.one_chip_env``), and a worker that finds no TPU chip, or more
+than one, exits before ``WORKER_READY`` with a message saying so.
+
+``--devices K`` (> 1) is the CPU harness the mesh tests use: it forces K
+host devices via ``XLA_FLAGS`` BEFORE jax is imported and builds the ring
+mesh over them, so a cluster can mix meshed workers (per-stage n²/8/S
 admission) with plain single-device ones, and the router's
-``WorkerLoad.mesh_devices`` model stays honest.
+``WorkerLoad.mesh_devices`` model stays honest. It is refused on any other
+backend.
 
 Ops (request ``{"op": ...}`` → reply ``{"ok": True, ...}``; failures
 reply ``{"ok": False, "etype", "error"}`` and the worker keeps serving):
@@ -44,10 +52,15 @@ def _parse(argv):
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--port", type=int, default=0,
                     help="TCP port to bind (0 = ephemeral, printed on stdout)")
-    ap.add_argument("--memory-bytes", type=int, required=True,
-                    help="device-state budget this worker advertises")
+    ap.add_argument("--memory-bytes", type=int, default=None,
+                    help="device-state budget this worker advertises "
+                         "(default: the device's own bytes_limit)")
     ap.add_argument("--devices", type=int, default=1,
-                    help="forced host device count (>1 builds a ring mesh)")
+                    help="forced CPU host device count (>1 builds a ring "
+                         "mesh; CPU backend only)")
+    ap.add_argument("--chip", type=int, default=None,
+                    help="the TPU chip this worker was pinned to (the "
+                         "spawner sets the pinning environment)")
     ap.add_argument("--max-stages", type=int, default=None,
                     help="planner ring-width cap (default: --devices)")
     ap.add_argument("--block-size", type=int, default=0,
@@ -58,20 +71,46 @@ def _parse(argv):
     return ap.parse_args(argv)
 
 
+def _check_devices(devices, args) -> None:
+    """A worker owns one device: one TPU chip on a TPU host, or the CPU
+    (with ``--devices`` forced host devices for the mesh harness). Anything
+    else fails the worker before READY, so its spawner reports why instead
+    of planning on the wrong device."""
+    platform = devices[0].platform
+    if args.chip is not None and platform != "tpu":
+        raise RuntimeError(
+            f"worker pinned to TPU chip {args.chip} found no free TPU chip "
+            f"(JAX found {len(devices)} {platform} device(s))")
+    if platform == "cpu":
+        return
+    if args.devices > 1:
+        raise RuntimeError(
+            f"--devices {args.devices} forces CPU host devices; on a "
+            f"{platform} host a worker owns exactly one chip")
+    if len(devices) != 1:
+        raise RuntimeError(
+            f"worker sees {len(devices)} {platform} devices; a worker owns "
+            f"exactly one chip — spawn it with chip=<index>")
+
+
 def _build_mux(args):
     # imports live HERE, after XLA_FLAGS is set, so the forced device
     # count is visible to jax's first initialization
+    import jax
+
     from repro.api import Resources, TriangleCounter
     from repro.serve.sessions import StreamMultiplexer
 
+    _check_devices(jax.devices(), args)
     mesh = None
     if args.devices > 1:
         from repro.launch.mesh import make_ring_mesh
 
         mesh = make_ring_mesh(args.devices)
-    res = Resources(memory_bytes=args.memory_bytes, n_devices=args.devices,
-                    max_stages=(args.max_stages if args.max_stages is not None
-                                else args.devices))
+    res = Resources.detect(
+        memory_bytes=args.memory_bytes,
+        max_stages=(args.max_stages if args.max_stages is not None
+                    else args.devices))
     counter = TriangleCounter(res, mesh=mesh)
     mux = StreamMultiplexer(counter, block_size=args.block_size or None,
                             prefetch_depth=args.prefetch_depth or None)
@@ -180,12 +219,15 @@ def serve(conn, mux, res, mesh_devices) -> None:
 
 
 def main(argv=None) -> int:
+    from repro.utils import enable_compile_cache
+
     args = _parse(argv)
     if args.devices > 1:
         flags = os.environ.get("XLA_FLAGS", "")
         forced = f"--xla_force_host_platform_device_count={args.devices}"
         if forced not in flags:
             os.environ["XLA_FLAGS"] = f"{flags} {forced}".strip()
+    enable_compile_cache()
     mux, res, mesh_devices = _build_mux(args)
     srv = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
     srv.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)

@@ -124,7 +124,7 @@ class TriangleServer:
         results: list = [None] * len(graphs)
         # node bucket -> (the group's planner plan, request indices). The
         # plan rides along so count_batch executes the planner's backend
-        # decision (use_kernel/interpret) instead of Plan defaults — on TPU
+        # decision (use_kernel) instead of Plan defaults — on TPU
         # the batched path must run the compiled kernels too.
         batchable: dict[int, tuple] = {}
         for i, g in enumerate(graphs):

@@ -153,7 +153,7 @@ def test_bitset_ring_use_kernel_end_to_end():
     g = gen.gnp(96, 0.4, seed=5)
     want = count_triangles_brute(g)
     assert count_triangles_bitset_ring(g, n_stages=3, sequential=True,
-                                       use_kernel=True, interpret=True) == want
+                                       use_kernel=True) == want
 
 
 # --------------------------------------------------------------------------

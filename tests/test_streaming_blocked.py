@@ -19,6 +19,7 @@ from repro.api import GraphStats, Plan, Resources, TriangleCounter, plan, stream
 from repro.core import streaming
 from repro.core.triangle_ref import count_triangles_brute
 from repro.graphs import generators as gen
+from repro.utils import interpret_mode
 
 
 def _stream_of(g, *, seed=0, dups=0, self_loops=0, reversed_dups=0):
@@ -54,7 +55,7 @@ def test_blocked_ingest_matches_per_edge_oracle(n, p, seed, block):
     want = count_triangles_brute(g)
     assert streaming.count_stream_per_edge(n, blocks) == want  # oracle sanity
     assert streaming.count_stream(n, blocks) == want
-    assert streaming.count_stream(n, blocks, use_kernel=True, interpret=True) == want
+    assert streaming.count_stream(n, blocks, use_kernel=True) == want
 
 
 @pytest.mark.parametrize("n_stages", [2, 3, 5])
@@ -236,21 +237,22 @@ def test_stream_plan_warns_when_even_full_ring_does_not_fit():
 # --------------------------------------------------------------------------
 def test_batch_plan_derived_from_resources():
     assert TriangleCounter(Resources(backend="tpu")).batch_plan().use_kernel
-    assert not TriangleCounter(Resources(backend="tpu")).batch_plan().interpret
     cpu = TriangleCounter(Resources(backend="cpu")).batch_plan()
-    assert not cpu.use_kernel and cpu.interpret
+    assert not cpu.use_kernel
+    # compiled-vs-interpreted is the live backend's call, not a plan field
+    assert "interpret" not in cpu.to_dict() and interpret_mode()
     with pytest.raises(ValueError, match="dense"):
         TriangleCounter().count_batch([gen.gnp(10, 0.5, seed=0)],
                                       plan=Plan(method="stream"))
 
 
 def test_count_batch_executes_plan_backend():
-    """The vmapped executable must honor the plan's use_kernel/interpret —
-    the regression built Plan(method='dense') defaults and dropped both."""
+    """The vmapped executable must honor the plan's use_kernel — the
+    regression built Plan(method='dense') defaults and dropped it."""
     graphs = [gen.gnp(n, 0.5, seed=n) for n in (18, 25, 31)]
     want = [count_triangles_brute(g) for g in graphs]
     res = TriangleCounter().count_batch(
-        graphs, plan=Plan(method="dense", use_kernel=True, interpret=True))
+        graphs, plan=Plan(method="dense", use_kernel=True))
     assert [int(x) for x in np.asarray(res.count)] == want
     assert res.plan.use_kernel is True
 

@@ -85,8 +85,7 @@ def test_windowed_matches_recount_oracle(n, window, n_epochs, m, seed):
     assert got == want
     # kernel-routed phase sweeps are bit-identical too
     got_k = streaming.count_windowed_stream(n, [[e] for e in epochs], window,
-                                            block_size=16, use_kernel=True,
-                                            interpret=True)
+                                            block_size=16, use_kernel=True)
     assert got_k == want
 
 

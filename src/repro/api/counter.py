@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.api.planner import GraphStats, Plan, Resources, plan as plan_fn
+from repro.tracing import span
 from repro.utils import require_count_capacity, triangle_bound
 
 
@@ -219,7 +220,11 @@ class TriangleCounter:
     def plan_for(self, g, *, allow: set[str] | None = None) -> Plan:
         if self.fixed_plan is not None:
             return self.fixed_plan
-        return plan_fn(GraphStats.from_graph(g), self.resources, allow=allow)
+        with span("plan"):
+            with span("plan.stats"):
+                stats = GraphStats.from_graph(g)
+            with span("plan.choose"):
+                return plan_fn(stats, self.resources, allow=allow)
 
     # -- compile cache -----------------------------------------------------
     def _entry(self, key: tuple, make) -> _Entry:
@@ -248,19 +253,23 @@ class TriangleCounter:
         execution knob comes from the resolved plan, never from defaults.
         The executable is cached under ``(plan.cache_key(), shape bucket)``:
         operands pad to power-of-two buckets, so same-bucket graphs reuse one
-        trace across calls (``stats["cache"]`` records key/hit/traces)."""
-        p = plan or self.plan_for(g)
-        t0 = time.perf_counter()
-        executor = getattr(self, f"_run_{p.method}", None)
-        if executor is None:
-            raise ValueError(f"plan method {p.method!r} not executable here")
-        if p.method != "stream":  # a stream session guards its own feeds
-            require_count_capacity(triangle_bound(g.n_nodes, g.n_edges),
-                                   f"{p.method} count of a {g.n_nodes}-node, "
-                                   f"{g.n_edges}-edge graph")
-        count, stats = executor(g, p)
-        return CountResult(count=count, plan=p,
-                           wall_s=time.perf_counter() - t0, stats=stats)
+        trace across calls (``stats["cache"]`` records key/hit/traces).
+        Traced as ``repro.count``, with the executor's
+        ``repro.count.operands`` (host operand build), ``repro.count.put``
+        (host-to-device copies) and ``repro.count.dispatch`` inside."""
+        with span("count"):
+            p = plan or self.plan_for(g)
+            t0 = time.perf_counter()
+            executor = getattr(self, f"_run_{p.method}", None)
+            if executor is None:
+                raise ValueError(f"plan method {p.method!r} not executable here")
+            if p.method != "stream":  # a stream session guards its own feeds
+                require_count_capacity(triangle_bound(g.n_nodes, g.n_edges),
+                                       f"{p.method} count of a {g.n_nodes}-node, "
+                                       f"{g.n_edges}-edge graph")
+            count, stats = executor(g, p)
+            return CountResult(count=count, plan=p,
+                               wall_s=time.perf_counter() - t0, stats=stats)
 
     def open_stream(self, n_nodes: int, *, plan: Plan | None = None,
                     block_size: int | None = None,
@@ -496,12 +505,17 @@ class TriangleCounter:
     def _run_dense(self, g, p: Plan):
         from repro.graphs.formats import forward_adjacency_dense
 
-        n_b = bucket(g.n_nodes)
-        u = np.zeros((n_b, n_b), np.float32)
-        u[: g.n_nodes, : g.n_nodes] = forward_adjacency_dense(g)
+        with span("count.operands"):
+            n_b = bucket(g.n_nodes)
+            u = np.zeros((n_b, n_b), np.float32)
+            u[: g.n_nodes, : g.n_nodes] = forward_adjacency_dense(g)
         key = (p.cache_key(), (n_b,))
         entry = self._entry(key, lambda e: self._make_dense(e, p))
-        return entry.fn(jnp.asarray(u)), {"cache": self._cache_stats(key, entry)}
+        with span("count.put"):
+            u = jnp.asarray(u)
+        with span("count.dispatch"):
+            out = entry.fn(u)
+        return out, {"cache": self._cache_stats(key, entry)}
 
     def _make_dense(self, entry: _Entry, p: Plan):
         from repro.core.triangle_pipeline import count_triangles_dense
@@ -525,24 +539,28 @@ class TriangleCounter:
     def _run_sparse(self, g, p: Plan):
         from repro.graphs.formats import degree_order, forward_adjacency_padded
 
-        rank = degree_order(g)
-        nbrs, _ = forward_adjacency_padded(g, rank)
-        n, md = nbrs.shape
-        n_b = bucket(n)
-        md_b = bucket(max(md, 1), minimum=8)
-        # re-sentinel into bucket space: padding value must equal n_pad = n_b
-        nb = np.full((n_b, md_b), n_b, np.int32)
-        nb[:n, :md] = np.where(nbrs == n, n_b, nbrs)
-        ru = rank[g.edges[:, 0]]
-        rv = rank[g.edges[:, 1]]
-        edges = np.stack([np.minimum(ru, rv), np.maximum(ru, rv)], axis=1)
-        m_b = bucket(max(g.n_edges, 1), minimum=256)
-        ed = np.full((m_b, 2), n_b, np.int32)
-        ed[: g.n_edges] = edges
+        with span("count.operands"):
+            rank = degree_order(g)
+            nbrs, _ = forward_adjacency_padded(g, rank)
+            n, md = nbrs.shape
+            n_b = bucket(n)
+            md_b = bucket(max(md, 1), minimum=8)
+            # re-sentinel into bucket space: padding value must equal n_pad = n_b
+            nb = np.full((n_b, md_b), n_b, np.int32)
+            nb[:n, :md] = np.where(nbrs == n, n_b, nbrs)
+            ru = rank[g.edges[:, 0]]
+            rv = rank[g.edges[:, 1]]
+            edges = np.stack([np.minimum(ru, rv), np.maximum(ru, rv)], axis=1)
+            m_b = bucket(max(g.n_edges, 1), minimum=256)
+            ed = np.full((m_b, 2), n_b, np.int32)
+            ed[: g.n_edges] = edges
         key = (p.cache_key(), (n_b, md_b, m_b))
         entry = self._entry(key, lambda e: self._make_sparse(e, p))
-        return entry.fn(jnp.asarray(nb), jnp.asarray(ed)), \
-            {"cache": self._cache_stats(key, entry)}
+        with span("count.put"):
+            nb, ed = jnp.asarray(nb), jnp.asarray(ed)
+        with span("count.dispatch"):
+            out = entry.fn(nb, ed)
+        return out, {"cache": self._cache_stats(key, entry)}
 
     def _make_sparse(self, entry: _Entry, p: Plan):
         from repro.core.triangle_pipeline import count_triangles_sparse
@@ -555,39 +573,40 @@ class TriangleCounter:
 
     def _run_ring(self, g, p: Plan):
         from repro.core.dynamic_pipeline import DynamicPipeline, run_sequential
-        from repro.core.partition import stage_costs
         from repro.core.triangle_pipeline import build_dense_ring_operands, dense_ring_spec
 
         # pad_to a power-of-two per-stage row count: same-bucket graphs share
         # the block shapes, hence the compiled ring
         pad_to = bucket(max(-(-g.n_nodes // p.n_stages), 1), minimum=8)
-        part, blocks = build_dense_ring_operands(g, p.n_stages, balance=p.balance,
-                                                 pad_to=pad_to)
+        with span("count.operands"):
+            part, blocks = build_dense_ring_operands(g, p.n_stages, balance=p.balance,
+                                                     pad_to=pad_to)
         spec = dense_ring_spec(part.rows_per_stage, use_kernel=p.use_kernel)
-        blocks = jnp.asarray(blocks)
+        with span("count.put"):
+            blocks = jnp.asarray(blocks)
         key = (p.cache_key(), ("ring", p.n_stages, part.rows_per_stage))
         if self.mesh_matches(p.n_stages):
             entry = self._entry(key, lambda e: self._mark_traced(
                 e, DynamicPipeline(self.mesh, self.mesh.axis_names[0]).jit(spec)))
-            out = entry.fn(blocks, blocks)
         else:
             entry = self._entry(key, lambda e: self._mark_traced(
                 e, lambda r, s: run_sequential(spec, r, s, p.n_stages)))
+        with span("count.dispatch"):
             out = entry.fn(blocks, blocks)
-        return out, {"cache": self._cache_stats(key, entry),
-                     "stage_costs": stage_costs(g, part).tolist()}
+        return out, {"cache": self._cache_stats(key, entry)}
 
     def _run_bitset_ring(self, g, p: Plan):
         from repro.core.dynamic_pipeline import DynamicPipeline, run_sequential
-        from repro.core.partition import stage_costs
         from repro.core.triangle_pipeline import bitset_ring_spec, build_bitset_ring_operands
 
         pad_to = bucket(max(-(-g.n_nodes // p.n_stages), 1), minimum=8)
         edge_block = bucket(max(-(-g.n_edges // p.n_stages), 1), minimum=128)
-        part, masks, edges = build_bitset_ring_operands(
-            g, p.n_stages, balance=p.balance, pad_to=pad_to, edge_block=edge_block)
+        with span("count.operands"):
+            _, masks, edges = build_bitset_ring_operands(
+                g, p.n_stages, balance=p.balance, pad_to=pad_to, edge_block=edge_block)
         spec = bitset_ring_spec(use_kernel=p.use_kernel)
-        masks, edges = jnp.asarray(masks), jnp.asarray(edges)
+        with span("count.put"):
+            masks, edges = jnp.asarray(masks), jnp.asarray(edges)
         key = (p.cache_key(), ("bitset", p.n_stages) + tuple(masks.shape) + tuple(edges.shape))
         if self.mesh_matches(p.n_stages):
             entry = self._entry(key, lambda e: self._mark_traced(
@@ -595,9 +614,9 @@ class TriangleCounter:
         else:
             entry = self._entry(key, lambda e: self._mark_traced(
                 e, lambda r, s: run_sequential(spec, r, s, p.n_stages)))
-        out = entry.fn(masks, edges)
-        return out, {"cache": self._cache_stats(key, entry),
-                     "stage_costs": stage_costs(g, part).tolist()}
+        with span("count.dispatch"):
+            out = entry.fn(masks, edges)
+        return out, {"cache": self._cache_stats(key, entry)}
 
     def mesh_matches(self, n_stages: int) -> bool:
         """True when this counter's mesh actually hosts a ``n_stages``-wide
@@ -630,19 +649,23 @@ class TriangleCounter:
                     f"mapreduce path needs jax_enable_x64 for n_nodes > {cap} "
                     f"(pair keys overflow int32); got {g.n_nodes}")
             n_b = cap
-        nbrs, keys, n = build_mapreduce_operands(g, key_base=n_b)
-        _, dmax = nbrs.shape
-        d_b = bucket(max(dmax, 1), minimum=8)
-        # bucket space: sentinel and key base both become n_b
-        nb = np.full((n_b, d_b), n_b, np.int64)
-        nb[:n, :dmax] = np.where(nbrs == n, n_b, nbrs)
-        m_b = bucket(max(g.n_edges, 1), minimum=256)
-        ks = np.full(m_b, np.int64(n_b) * n_b, np.int64)  # > any real key
-        ks[: g.n_edges] = keys
+        with span("count.operands"):
+            nbrs, keys, n = build_mapreduce_operands(g, key_base=n_b)
+            _, dmax = nbrs.shape
+            d_b = bucket(max(dmax, 1), minimum=8)
+            # bucket space: sentinel and key base both become n_b
+            nb = np.full((n_b, d_b), n_b, np.int64)
+            nb[:n, :dmax] = np.where(nbrs == n, n_b, nbrs)
+            m_b = bucket(max(g.n_edges, 1), minimum=256)
+            ks = np.full(m_b, np.int64(n_b) * n_b, np.int64)  # > any real key
+            ks[: g.n_edges] = keys
         key = (p.cache_key(), (n_b, d_b, m_b))
         entry = self._entry(key, lambda e: self._make_mapreduce(e, p, n_b))
-        return entry.fn(jnp.asarray(nb), jnp.asarray(ks)), \
-            {"cache": self._cache_stats(key, entry)}
+        with span("count.put"):
+            nb, ks = jnp.asarray(nb), jnp.asarray(ks)
+        with span("count.dispatch"):
+            out = entry.fn(nb, ks)
+        return out, {"cache": self._cache_stats(key, entry)}
 
     def _make_mapreduce(self, entry: _Entry, p: Plan, n_b: int):
         from repro.core.triangle_mapreduce import _mapreduce_count
@@ -767,14 +790,18 @@ class StreamSession:
         outside (or wrap around inside) the bitset."""
         if self.result is not None:
             raise RuntimeError("session already finalized")
-        from repro.core import streaming
+        with span("session.feed"):
+            edges = self._admit_edges(edges)
+            t0 = time.perf_counter()
+            for b in self._buffer.push(edges):
+                self._ingest(b)
+            self._wall += time.perf_counter() - t0
 
-        edges = self._admit_edges(edges)
-        t0 = time.perf_counter()
-        for b in self._buffer.push(edges):
-            self.state = self._entry.fn(self.state, b)
-            self.n_blocks += 1
-        self._wall += time.perf_counter() - t0
+    def _ingest(self, block) -> None:
+        """Dispatch one fixed-shape block into the state (``repro.session.ingest``)."""
+        with span("session.ingest"):
+            self.state = self._entry.fn(self.state, block)
+        self.n_blocks += 1
 
     # -- async prefetch surface (serve.sessions._PrefetchDriver) -----------
     # feed() = reblock() + ingest_ready() per emitted block, split so a
@@ -839,8 +866,7 @@ class StreamSession:
         if self.result is not None:
             raise RuntimeError("session already finalized")
         t0 = time.perf_counter()
-        self.state = self._entry.fn(self.state, block)
-        self.n_blocks += 1
+        self._ingest(block)
         self._wall += time.perf_counter() - t0
 
     def expire_ready(self) -> None:
@@ -856,10 +882,11 @@ class StreamSession:
                 "window=E (or a plan with window_epochs > 0)")
         from repro.core import streaming
 
-        t0 = time.perf_counter()
-        self.state = streaming.expire_epoch(self.state)
-        self.n_epochs_advanced += 1
-        self._wall += time.perf_counter() - t0
+        with span("session.advance"):
+            t0 = time.perf_counter()
+            self.state = streaming.expire_epoch(self.state)
+            self.n_epochs_advanced += 1
+            self._wall += time.perf_counter() - t0
 
     def set_block_size(self, block_size: int) -> list:
         """Adaptive re-blocking: change the emitted block shape from the
@@ -892,18 +919,18 @@ class StreamSession:
             raise RuntimeError("session already finalized")
         from repro.core import streaming
 
-        t0 = time.perf_counter()
-        tail = self._buffer.flush()
-        if tail is not None:
-            self.state = self._entry.fn(self.state, tail)
-            self.n_blocks += 1
-        arrays = streaming.snapshot_state(self.state)
-        if int(np.asarray(arrays.get("lost", 0))):
-            raise RuntimeError(
-                f"refusing to checkpoint a hybrid session that dropped "
-                f"{int(np.asarray(arrays['lost']))} edge endpoint(s) — the "
-                f"snapshot would persist an inexact count")
-        self._wall += time.perf_counter() - t0
+        with span("session.checkpoint"):
+            t0 = time.perf_counter()
+            tail = self._buffer.flush()
+            if tail is not None:
+                self._ingest(tail)
+            arrays = streaming.snapshot_state(self.state)
+            if int(np.asarray(arrays.get("lost", 0))):
+                raise RuntimeError(
+                    f"refusing to checkpoint a hybrid session that dropped "
+                    f"{int(np.asarray(arrays['lost']))} edge endpoint(s) — the "
+                    f"snapshot would persist an inexact count")
+            self._wall += time.perf_counter() - t0
         return SessionCheckpoint(
             n_nodes=self.n_nodes, plan=self.plan, block_size=self.block_size,
             state_bytes=self.state_bytes,
@@ -932,15 +959,15 @@ class StreamSession:
                 "(or a plan with window_epochs > 0)")
         from repro.core import streaming
 
-        t0 = time.perf_counter()
-        self._next_epoch_tally()
-        tail = self._buffer.flush()
-        if tail is not None:
-            self.state = self._entry.fn(self.state, tail)
-            self.n_blocks += 1
-        self.state = streaming.expire_epoch(self.state)
-        self.n_epochs_advanced += 1
-        self._wall += time.perf_counter() - t0
+        with span("session.advance"):
+            t0 = time.perf_counter()
+            self._next_epoch_tally()
+            tail = self._buffer.flush()
+            if tail is not None:
+                self._ingest(tail)
+            self.state = streaming.expire_epoch(self.state)
+            self.n_epochs_advanced += 1
+            self._wall += time.perf_counter() - t0
 
     def finalize(self) -> CountResult:
         """Flush the padded tail block and return the stream's
@@ -954,13 +981,16 @@ class StreamSession:
         session fed the shape first."""
         if self.result is not None:
             return self.result
+        with span("session.finalize"):
+            return self._finalize()
+
+    def _finalize(self) -> CountResult:
         from repro.core import streaming
 
         t0 = time.perf_counter()
         tail = self._buffer.flush()
         if tail is not None:
-            self.state = self._entry.fn(self.state, tail)
-            self.n_blocks += 1
+            self._ingest(tail)
         self._wall += time.perf_counter() - t0
         p = self.plan
         if p.state_layout == "hybrid":

@@ -16,6 +16,7 @@ Used by: triangle counting (dense + bitset rings), ring attention for the
 from __future__ import annotations
 
 import dataclasses
+import functools
 from functools import lru_cache, partial
 from typing import Any, Callable
 
@@ -161,10 +162,12 @@ class ShardedStateStream:
         (which must equal the ring width); ``carry`` and ``block`` are
         replicated, and the returned carry must already be identical across
         stages (psum inside the step). Memoized per step function so repeated
-        blocks of one stream reuse one compiled executable."""
+        blocks of one stream reuse one compiled executable, which is named
+        after the step (``jit_<step_fn.__name__>``)."""
         if step_fn not in self._jit_cache:
             ax = self.axis_name
 
+            @functools.wraps(step_fn)
             def stage_fn(state_local, carry, block):
                 # shard_map gives block-local views with leading axis 1; drop
                 # it for the step and restore it for the out_spec.

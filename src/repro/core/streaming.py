@@ -69,6 +69,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.tracing import INGEST_AGE_CUM, INGEST_LIVE, INGEST_TERMS, INGEST_UPDATE
 from repro.utils import count_dtype
 
 # The blocked-kernel path keeps the whole mask table VMEM-resident and the
@@ -249,17 +250,18 @@ def _canonical_live(edges: jax.Array, n: int) -> tuple[jax.Array, jax.Array, jax
     """(keep, lo, hi): canonicalized endpoints with self-loops/phantoms
     invalidated (lo = hi = n) and within-block duplicates reduced to their
     first occurrence. ``keep`` still needs the not-already-in-A check."""
-    e = edges.astype(jnp.int32)
-    u, v = e[:, 0], e[:, 1]
-    valid = (u < n) & (v < n) & (u != v)
-    lo = jnp.where(valid, jnp.minimum(u, v), n)
-    hi = jnp.where(valid, jnp.maximum(u, v), n)
-    order = jnp.lexsort((hi, lo))  # stable: first occurrence keeps block order
-    ls, hs = lo[order], hi[order]
-    dup = jnp.concatenate(
-        [jnp.zeros((1,), bool), (ls[1:] == ls[:-1]) & (hs[1:] == hs[:-1])])
-    first = jnp.zeros(e.shape[0], bool).at[order].set(~dup)
-    return valid & first, lo, hi
+    with jax.named_scope(INGEST_LIVE):
+        e = edges.astype(jnp.int32)
+        u, v = e[:, 0], e[:, 1]
+        valid = (u < n) & (v < n) & (u != v)
+        lo = jnp.where(valid, jnp.minimum(u, v), n)
+        hi = jnp.where(valid, jnp.maximum(u, v), n)
+        order = jnp.lexsort((hi, lo))  # stable: first occurrence keeps block order
+        ls, hs = lo[order], hi[order]
+        dup = jnp.concatenate(
+            [jnp.zeros((1,), bool), (ls[1:] == ls[:-1]) & (hs[1:] == hs[:-1])])
+        first = jnp.zeros(e.shape[0], bool).at[order].set(~dup)
+        return valid & first, lo, hi
 
 
 def _stage_seen(adj_s: jax.Array, lo: jax.Array, hi: jax.Array, off) -> jax.Array:
@@ -267,11 +269,12 @@ def _stage_seen(adj_s: jax.Array, lo: jax.Array, hi: jax.Array, off) -> jax.Arra
     (exactly one stage owns word hi//32, so summing over stages recovers
     the global bit)."""
     n, ws = adj_s.shape
-    wl = hi // 32 - off
-    owned = (wl >= 0) & (wl < ws) & (lo < n)
-    word = adj_s[jnp.clip(lo, 0, n - 1), jnp.clip(wl, 0, ws - 1)]
-    bit = (word >> (hi % 32).astype(jnp.uint32)) & jnp.uint32(1)
-    return jnp.where(owned, bit, jnp.uint32(0))
+    with jax.named_scope(INGEST_LIVE):
+        wl = hi // 32 - off
+        owned = (wl >= 0) & (wl < ws) & (lo < n)
+        word = adj_s[jnp.clip(lo, 0, n - 1), jnp.clip(wl, 0, ws - 1)]
+        bit = (word >> (hi % 32).astype(jnp.uint32)) & jnp.uint32(1)
+        return jnp.where(owned, bit, jnp.uint32(0))
 
 
 def _delta_scatter(n: int, ws: int, lo: jax.Array, hi: jax.Array,
@@ -291,8 +294,9 @@ def _delta_scatter(n: int, ws: int, lo: jax.Array, hi: jax.Array,
         # updates to one word carry distinct bits and add == bitwise-or
         return dst.at[r, c].add(bit)
 
-    delta = owned_scatter(jnp.zeros((n, ws), jnp.uint32), lo, hi)
-    return owned_scatter(delta, hi, lo)
+    with jax.named_scope(INGEST_UPDATE):
+        delta = owned_scatter(jnp.zeros((n, ws), jnp.uint32), lo, hi)
+        return owned_scatter(delta, hi, lo)
 
 
 def _kernel_fits(use_kernel: bool, table_bytes: int, n_edges: int) -> bool:
@@ -322,39 +326,46 @@ def _stage_update(adj_s: jax.Array, lo: jax.Array, hi: jax.Array,
     n, ws = adj_s.shape
     delta = _delta_scatter(n, ws, lo, hi, live, off)
 
-    glo = jnp.clip(lo, 0, n - 1)
-    ghi = jnp.clip(hi, 0, n - 1)
-    au, av = adj_s[glo], adj_s[ghi]
-    du, dv = delta[glo], delta[ghi]
-
     def masked_sum(words):
         pc = jax.lax.population_count(words).sum(axis=-1)
         return jnp.sum(jnp.where(live, pc, 0), dtype=count_dtype())
 
-    table_bytes = n * ws * 4
-    if _kernel_fits(use_kernel, table_bytes, lo.shape[0]):
-        from repro.kernels.bitset_count.ops import bitset_edge_count, bitset_pair_count
+    with jax.named_scope(INGEST_TERMS):
+        glo = jnp.clip(lo, 0, n - 1)
+        ghi = jnp.clip(hi, 0, n - 1)
+        au, av = adj_s[glo], adj_s[ghi]
+        du, dv = delta[glo], delta[ghi]
 
-        ek = _phantom_edges(lo, hi, live, n)
-        pre = bitset_edge_count(adj_s, ek)
-        if 2 * table_bytes <= _MASK_VMEM_BUDGET:  # pair kernel holds two tables
-            mixed = (bitset_pair_count(adj_s, delta, ek)
-                     + bitset_pair_count(delta, adj_s, ek))
-            dd = bitset_edge_count(delta, ek)
+        table_bytes = n * ws * 4
+        if _kernel_fits(use_kernel, table_bytes, lo.shape[0]):
+            from repro.kernels.bitset_count.ops import bitset_edge_count, bitset_pair_count
+
+            ek = _phantom_edges(lo, hi, live, n)
+            pre = bitset_edge_count(adj_s, ek)
+            if 2 * table_bytes <= _MASK_VMEM_BUDGET:  # pair kernel holds two tables
+                mixed = (bitset_pair_count(adj_s, delta, ek)
+                         + bitset_pair_count(delta, adj_s, ek))
+                dd = bitset_edge_count(delta, ek)
+            else:
+                mixed = masked_sum(au & dv) + masked_sum(du & av)
+                dd = masked_sum(du & dv)
         else:
+            pre = masked_sum(au & av)
             mixed = masked_sum(au & dv) + masked_sum(du & av)
             dd = masked_sum(du & dv)
-    else:
-        pre = masked_sum(au & av)
-        mixed = masked_sum(au & dv) + masked_sum(du & av)
-        dd = masked_sum(du & dv)
-    return adj_s | delta, jnp.stack([pre, mixed, dd])
+    # the state write sits between the sums and their stack, in the order
+    # the ops were always traced: the scopes leave the compiled HLO as it was
+    with jax.named_scope(INGEST_UPDATE):
+        adj_s = adj_s | delta
+    with jax.named_scope(INGEST_TERMS):
+        return adj_s, jnp.stack([pre, mixed, dd])
 
 
 def _combine(count, terms):
     # terms = full-width (pre, mixed, dd); integer divisions are exact (see
     # the multiplicities in the module docstring)
-    return count + terms[0] + terms[1] // 2 + terms[2] // 3
+    with jax.named_scope(INGEST_UPDATE):
+        return count + terms[0] + terms[1] // 2 + terms[2] // 3
 
 
 # --------------------------------------------------------------------------
@@ -372,8 +383,9 @@ def _age_cum(epochs_s: jax.Array, head) -> jax.Array:
     adjacency. Computed once per block and shared between the dedup check
     and the phase sweeps."""
     n_epochs = epochs_s.shape[0]
-    return jax.lax.associative_scan(
-        jnp.bitwise_or, epochs_s[_age_order(head, n_epochs)], axis=0)
+    with jax.named_scope(INGEST_AGE_CUM):
+        return jax.lax.associative_scan(
+            jnp.bitwise_or, epochs_s[_age_order(head, n_epochs)], axis=0)
 
 
 def _windowed_stage_update(epochs_s: jax.Array, cum: jax.Array,
@@ -400,40 +412,44 @@ def _windowed_stage_update(epochs_s: jax.Array, cum: jax.Array,
     n_epochs, n, ws = epochs_s.shape
     delta = _delta_scatter(n, ws, lo, hi, live, off)
 
-    glo = jnp.clip(lo, 0, n - 1)
-    ghi = jnp.clip(hi, 0, n - 1)
-    du, dv = delta[glo], delta[ghi]             # (B, ws)
-
     def masked_sum(words):
         # words: (..., B, ws) -> (...,) masked popcount over live edges
         pc = jax.lax.population_count(words).sum(axis=-1)
         return jnp.sum(jnp.where(live, pc, 0), axis=-1, dtype=count_dtype())
 
-    table_bytes = n * ws * 4
-    if _kernel_fits(use_kernel, table_bytes, lo.shape[0]):
-        from repro.kernels.bitset_count.ops import bitset_edge_count, bitset_pair_count
+    with jax.named_scope(INGEST_TERMS):
+        glo = jnp.clip(lo, 0, n - 1)
+        ghi = jnp.clip(hi, 0, n - 1)
+        du, dv = delta[glo], delta[ghi]             # (B, ws)
 
-        ek = _phantom_edges(lo, hi, live, n)
-        pair_ok = 2 * table_bytes <= _MASK_VMEM_BUDGET
-        ps, ms = [], []
-        for t in range(n_epochs):  # the unbounded kernels, once per epoch age
-            ps.append(bitset_edge_count(cum[t], ek))
-            if pair_ok:
-                ms.append(bitset_pair_count(cum[t], delta, ek)
-                          + bitset_pair_count(delta, cum[t], ek))
-            else:
-                cu, cv = cum[t][glo], cum[t][ghi]
-                ms.append(masked_sum(cu & dv) + masked_sum(du & cv))
-        p_terms = jnp.stack(ps)
-        m_terms = jnp.stack(ms)
-        dd = bitset_edge_count(delta, ek)
-    else:
-        cu, cv = cum[:, glo], cum[:, ghi]       # (E, B, ws)
-        p_terms = masked_sum(cu & cv)           # (E,)
-        m_terms = masked_sum(cu & dv[None]) + masked_sum(du[None] & cv)
-        dd = masked_sum(du & dv)
-    new = epochs_s.at[head].set(epochs_s[head] | delta)
-    return new, jnp.concatenate([p_terms, m_terms, dd[None]])
+        table_bytes = n * ws * 4
+        if _kernel_fits(use_kernel, table_bytes, lo.shape[0]):
+            from repro.kernels.bitset_count.ops import bitset_edge_count, bitset_pair_count
+
+            ek = _phantom_edges(lo, hi, live, n)
+            pair_ok = 2 * table_bytes <= _MASK_VMEM_BUDGET
+            ps, ms = [], []
+            for t in range(n_epochs):  # the unbounded kernels, once per epoch age
+                ps.append(bitset_edge_count(cum[t], ek))
+                if pair_ok:
+                    ms.append(bitset_pair_count(cum[t], delta, ek)
+                              + bitset_pair_count(delta, cum[t], ek))
+                else:
+                    cu, cv = cum[t][glo], cum[t][ghi]
+                    ms.append(masked_sum(cu & dv) + masked_sum(du & cv))
+            p_terms = jnp.stack(ps)
+            m_terms = jnp.stack(ms)
+            dd = bitset_edge_count(delta, ek)
+        else:
+            cu, cv = cum[:, glo], cum[:, ghi]       # (E, B, ws)
+            p_terms = masked_sum(cu & cv)           # (E,)
+            m_terms = masked_sum(cu & dv[None]) + masked_sum(du[None] & cv)
+            dd = masked_sum(du & dv)
+    # in the traced order, as in ``_stage_update``
+    with jax.named_scope(INGEST_UPDATE):
+        new = epochs_s.at[head].set(epochs_s[head] | delta)
+    with jax.named_scope(INGEST_TERMS):
+        return new, jnp.concatenate([p_terms, m_terms, dd[None]])
 
 
 def _windowed_combine(counts: jax.Array, terms: jax.Array, head) -> jax.Array:
@@ -448,11 +464,12 @@ def _windowed_combine(counts: jax.Array, terms: jax.Array, head) -> jax.Array:
     integer divisions are exact for full-width sums only — callers must
     psum/sum shards before calling this."""
     n_epochs = counts.shape[0]
-    p_terms, m_terms, dd = terms[:n_epochs], terms[n_epochs:2 * n_epochs], terms[-1]
-    pre_t = jnp.diff(p_terms, prepend=jnp.zeros((1,), p_terms.dtype))
-    mixed_t = jnp.diff(m_terms, prepend=jnp.zeros((1,), m_terms.dtype)) // 2
-    contrib = (pre_t + mixed_t).at[0].add(dd // 3)
-    return counts.at[_age_order(head, n_epochs)].add(contrib)
+    with jax.named_scope(INGEST_UPDATE):
+        p_terms, m_terms, dd = terms[:n_epochs], terms[n_epochs:2 * n_epochs], terms[-1]
+        pre_t = jnp.diff(p_terms, prepend=jnp.zeros((1,), p_terms.dtype))
+        mixed_t = jnp.diff(m_terms, prepend=jnp.zeros((1,), m_terms.dtype)) // 2
+        contrib = (pre_t + mixed_t).at[0].add(dd // 3)
+        return counts.at[_age_order(head, n_epochs)].add(contrib)
 
 
 def window_count(state: dict):
@@ -509,10 +526,14 @@ def _ingest_block_sharded_impl(state: dict, edges: jax.Array) -> dict:
     s, n, ws = adj.shape
     keep, lo, hi = _canonical_live(edges, n)
     offs = jnp.arange(s, dtype=jnp.int32) * ws
-    seen = jax.vmap(lambda a, o: _stage_seen(a, lo, hi, o))(adj, offs).sum(0)
+    seen = jax.vmap(lambda a, o: _stage_seen(a, lo, hi, o))(adj, offs)
+    with jax.named_scope(INGEST_LIVE):
+        seen = seen.sum(0)
     live = keep & (seen == 0)
     adj, terms = jax.vmap(lambda a, o: _stage_update(a, lo, hi, live, o))(adj, offs)
-    return {"adj": adj, "count": _combine(state["count"], terms.sum(0))}
+    with jax.named_scope(INGEST_TERMS):
+        terms = terms.sum(0)
+    return {"adj": adj, "count": _combine(state["count"], terms)}
 
 
 ingest_block_sharded = jax.jit(_ingest_block_sharded_impl)
@@ -537,18 +558,22 @@ def make_mesh_ingest(mesh, axis_name: str | None = None, *,
     runtime = ShardedStateStream.shared(mesh, axis_name or mesh.axis_names[0])
     ax = runtime.axis_name
 
-    def step(adj_s, carry, edges):
+    def ingest_block_mesh(adj_s, carry, edges):
         _INGEST_TRACES[0] += 1
         n, ws = adj_s.shape
         off = jax.lax.axis_index(ax) * ws
         keep, lo, hi = _canonical_live(edges, n)
-        seen = jax.lax.psum(_stage_seen(adj_s, lo, hi, off), ax)
+        seen = _stage_seen(adj_s, lo, hi, off)
+        with jax.named_scope(INGEST_LIVE):
+            seen = jax.lax.psum(seen, ax)
         live = keep & (seen == 0)
         adj_s, terms = _stage_update(adj_s, lo, hi, live, off,
                                      use_kernel=use_kernel)
-        return adj_s, _combine(carry, jax.lax.psum(terms, ax))
+        with jax.named_scope(INGEST_TERMS):
+            terms = jax.lax.psum(terms, ax)
+        return adj_s, _combine(carry, terms)
 
-    fn = runtime.jit_step(step)
+    fn = runtime.jit_step(ingest_block_mesh)
 
     def ingest(state: dict, edges: jax.Array) -> dict:
         adj, count = fn(state["adj"], state["count"], edges)
@@ -613,14 +638,17 @@ def _ingest_block_windowed_sharded_impl(state: dict, edges: jax.Array) -> dict:
     keep, lo, hi = _canonical_live(edges, n)
     offs = jnp.arange(s, dtype=jnp.int32) * ws
     cums = jax.vmap(lambda e: _age_cum(e, head))(epochs)  # (S, E, n, Ws)
-    seen = jax.vmap(lambda c, o: _stage_seen(c[-1], lo, hi, o))(
-        cums, offs).sum(0)
+    seen = jax.vmap(lambda c, o: _stage_seen(c[-1], lo, hi, o))(cums, offs)
+    with jax.named_scope(INGEST_LIVE):
+        seen = seen.sum(0)
     live = keep & (seen == 0)
     epochs, terms = jax.vmap(
         lambda e, c, o: _windowed_stage_update(e, c, lo, hi, live, o, head))(
         epochs, cums, offs)
+    with jax.named_scope(INGEST_TERMS):
+        terms = terms.sum(0)
     return {"epochs": epochs,
-            "counts": _windowed_combine(state["counts"], terms.sum(0), head),
+            "counts": _windowed_combine(state["counts"], terms, head),
             "head": head}
 
 
@@ -646,21 +674,25 @@ def make_mesh_ingest_windowed(mesh, axis_name: str | None = None, *,
     runtime = ShardedStateStream.shared(mesh, axis_name or mesh.axis_names[0])
     ax = runtime.axis_name
 
-    def step(epochs_s, carry, edges):
+    def ingest_block_windowed_mesh(epochs_s, carry, edges):
         _INGEST_TRACES[0] += 1
         counts, head = carry
         _, n, ws = epochs_s.shape
         off = jax.lax.axis_index(ax) * ws
         keep, lo, hi = _canonical_live(edges, n)
         cum = _age_cum(epochs_s, head)  # cum[-1] = this shard's live words
-        seen = jax.lax.psum(_stage_seen(cum[-1], lo, hi, off), ax)
+        seen = _stage_seen(cum[-1], lo, hi, off)
+        with jax.named_scope(INGEST_LIVE):
+            seen = jax.lax.psum(seen, ax)
         live = keep & (seen == 0)
         epochs_s, terms = _windowed_stage_update(
             epochs_s, cum, lo, hi, live, off, head, use_kernel=use_kernel)
-        counts = _windowed_combine(counts, jax.lax.psum(terms, ax), head)
+        with jax.named_scope(INGEST_TERMS):
+            terms = jax.lax.psum(terms, ax)
+        counts = _windowed_combine(counts, terms, head)
         return epochs_s, (counts, head)
 
-    fn = runtime.jit_step(step)
+    fn = runtime.jit_step(ingest_block_windowed_mesh)
 
     def ingest(state: dict, edges: jax.Array) -> dict:
         epochs, (counts, head) = fn(
@@ -900,123 +932,127 @@ def _ingest_block_hybrid_impl(state: dict, edges: jax.Array, *,
         rows = jnp.where((slot >= 0)[:, None], hubrow, tailrow)
         return jnp.where((v < n)[:, None], rows, jnp.uint32(0))
 
-    rows_lo = full_rows(lo)
-    rows_hi = full_rows(hi)
+    with jax.named_scope(INGEST_TERMS):
+        rows_lo = full_rows(lo)
+        rows_hi = full_rows(hi)
 
-    # dedup against A: bit hi of lo's row (rows are symmetric by insertion)
-    word = rows_lo[jnp.arange(b), jnp.clip(hi // 32, 0, w - 1)]
-    seen = (word >> (hi % 32).astype(jnp.uint32)) & jnp.uint32(1)
-    live = keep & (seen == 0)
+    with jax.named_scope(INGEST_LIVE):
+        # dedup against A: bit hi of lo's row (rows are symmetric by insertion)
+        word = rows_lo[jnp.arange(b), jnp.clip(hi // 32, 0, w - 1)]
+        seen = (word >> (hi % 32).astype(jnp.uint32)) & jnp.uint32(1)
+        live = keep & (seen == 0)
 
     def masked_sum(words, mask):
         pc = jax.lax.population_count(words).sum(axis=-1)
         return jnp.sum(jnp.where(mask, pc, 0), dtype=count_dtype())
 
-    pre = masked_sum(rows_lo & rows_hi, live)
+    with jax.named_scope(INGEST_TERMS):
+        pre = masked_sum(rows_lo & rows_hi, live)
 
-    # ---- block-local vertex space for the intra-block correction ----
-    big = 2 * b
-    wl = -(-big // 32)
-    rlo = jnp.where(live, lo, n)
-    rhi = jnp.where(live, hi, n)
-    verts = jnp.concatenate([rlo, rhi])      # one occurrence per endpoint
-    others = jnp.concatenate([rhi, rlo])     # the occurrence's neighbor
-    liveo = jnp.concatenate([live, live])
-    order = jnp.argsort(verts, stable=True)
-    sv = verts[order]
-    firsts = jnp.concatenate([jnp.ones((1,), bool), sv[1:] != sv[:-1]])
-    lid_sorted = (jnp.cumsum(firsts) - 1).astype(jnp.int32)
-    lid = jnp.zeros((big,), jnp.int32).at[order].set(lid_sorted)
-    # global vertex per local id (dead occurrences share the id of value n)
-    gvert = jnp.full((big,), n, jnp.int32).at[lid_sorted].set(sv)
+        # ---- block-local vertex space for the intra-block correction ----
+        big = 2 * b
+        wl = -(-big // 32)
+        rlo = jnp.where(live, lo, n)
+        rhi = jnp.where(live, hi, n)
+        verts = jnp.concatenate([rlo, rhi])      # one occurrence per endpoint
+        others = jnp.concatenate([rhi, rlo])     # the occurrence's neighbor
+        liveo = jnp.concatenate([live, live])
+        order = jnp.argsort(verts, stable=True)
+        sv = verts[order]
+        firsts = jnp.concatenate([jnp.ones((1,), bool), sv[1:] != sv[:-1]])
+        lid_sorted = (jnp.cumsum(firsts) - 1).astype(jnp.int32)
+        lid = jnp.zeros((big,), jnp.int32).at[order].set(lid_sorted)
+        # global vertex per local id (dead occurrences share the id of value n)
+        gvert = jnp.full((big,), n, jnp.int32).at[lid_sorted].set(sv)
 
-    # D in local space: each live edge's two bits, one scatter each way
-    l_lo, l_hi = lid[:b], lid[b:]
+        # D in local space: each live edge's two bits, one scatter each way
+        l_lo, l_hi = lid[:b], lid[b:]
 
-    def dscat(dst, row, cvert):
-        rr = jnp.where(live, row, big)  # dead edges scatter out of bounds
-        bit = jnp.where(live, jnp.uint32(1) << (cvert % 32).astype(jnp.uint32),
-                        jnp.uint32(0))
-        return dst.at[rr, cvert // 32].add(bit)
+        def dscat(dst, row, cvert):
+            rr = jnp.where(live, row, big)  # dead edges scatter out of bounds
+            bit = jnp.where(live, jnp.uint32(1) << (cvert % 32).astype(jnp.uint32),
+                            jnp.uint32(0))
+            return dst.at[rr, cvert // 32].add(bit)
 
-    dloc = dscat(dscat(jnp.zeros((big, wl), jnp.uint32), l_lo, l_hi), l_hi, l_lo)
+        dloc = dscat(dscat(jnp.zeros((big, wl), jnp.uint32), l_lo, l_hi), l_hi, l_lo)
 
-    # A restricted to block-vertex columns, per occurrence, packed to words
-    rows_cat = jnp.concatenate([rows_lo, rows_hi])          # (2B, W)
-    gw = jnp.clip(gvert // 32, 0, w - 1)
-    abit = (rows_cat[:, gw] >> (gvert % 32).astype(jnp.uint32)[None, :]) \
-        & jnp.uint32(1)                                      # (2B, L)
-    abit = jnp.where((gvert < n)[None, :], abit, jnp.uint32(0))
-    abit = jnp.pad(abit, ((0, 0), (0, wl * 32 - big)))
-    aloc = (abit.reshape(big, wl, 32)
-            << jnp.arange(32, dtype=jnp.uint32)[None, None, :]).sum(
-        axis=-1, dtype=jnp.uint32)                           # (2B, Wl)
+        # A restricted to block-vertex columns, per occurrence, packed to words
+        rows_cat = jnp.concatenate([rows_lo, rows_hi])          # (2B, W)
+        gw = jnp.clip(gvert // 32, 0, w - 1)
+        abit = (rows_cat[:, gw] >> (gvert % 32).astype(jnp.uint32)[None, :]) \
+            & jnp.uint32(1)                                      # (2B, L)
+        abit = jnp.where((gvert < n)[None, :], abit, jnp.uint32(0))
+        abit = jnp.pad(abit, ((0, 0), (0, wl * 32 - big)))
+        aloc = (abit.reshape(big, wl, 32)
+                << jnp.arange(32, dtype=jnp.uint32)[None, None, :]).sum(
+            axis=-1, dtype=jnp.uint32)                           # (2B, Wl)
 
-    d_lo = dloc[jnp.clip(l_lo, 0, big - 1)]
-    d_hi = dloc[jnp.clip(l_hi, 0, big - 1)]
-    mixed = masked_sum(aloc[:b] & d_hi, live) + masked_sum(d_lo & aloc[b:], live)
-    dd = masked_sum(d_lo & d_hi, live)
+        d_lo = dloc[jnp.clip(l_lo, 0, big - 1)]
+        d_hi = dloc[jnp.clip(l_hi, 0, big - 1)]
+        mixed = masked_sum(aloc[:b] & d_hi, live) + masked_sum(d_lo & aloc[b:], live)
+        dd = masked_sum(d_lo & d_hi, live)
     count = _combine(state["count"], jnp.stack([pre, mixed, dd]))
 
-    # ---- promotion (BEFORE insertion, on pre-block buffers) ----
-    occ = jnp.zeros((big,), jnp.int32).at[jnp.where(liveo, lid, big)].add(1)
-    real = gvert < n
-    gv_ok = jnp.clip(gvert, 0, n - 1)
-    is_tail = jnp.where(real, hub_slot[gv_ok] < 0, False)
-    newdeg = jnp.where(real, deg[gv_ok], 0) + occ
-    touched = is_tail & (occ > 0)
-    must = touched & (newdeg > c)            # buffer would overflow
-    want = touched & (newdeg >= hub_threshold)
-    cand = must | want
-    free = hub_ids == n
-    n_free = jnp.sum(free.astype(jnp.int32))
-    # mandatory promotions claim free slots before policy ones
-    mrank = jnp.cumsum(must.astype(jnp.int32)) - 1
-    wrank = jnp.sum(must.astype(jnp.int32)) \
-        + jnp.cumsum((cand & ~must).astype(jnp.int32)) - 1
-    prank = jnp.where(must, mrank, wrank)
-    slot_for = jnp.argsort(~free, stable=True)[jnp.clip(prank, 0, h - 1)]
-    ok = cand & (prank < n_free) & (prank < h)
+    with jax.named_scope(INGEST_UPDATE):
+        # ---- promotion (BEFORE insertion, on pre-block buffers) ----
+        occ = jnp.zeros((big,), jnp.int32).at[jnp.where(liveo, lid, big)].add(1)
+        real = gvert < n
+        gv_ok = jnp.clip(gvert, 0, n - 1)
+        is_tail = jnp.where(real, hub_slot[gv_ok] < 0, False)
+        newdeg = jnp.where(real, deg[gv_ok], 0) + occ
+        touched = is_tail & (occ > 0)
+        must = touched & (newdeg > c)            # buffer would overflow
+        want = touched & (newdeg >= hub_threshold)
+        cand = must | want
+        free = hub_ids == n
+        n_free = jnp.sum(free.astype(jnp.int32))
+        # mandatory promotions claim free slots before policy ones
+        mrank = jnp.cumsum(must.astype(jnp.int32)) - 1
+        wrank = jnp.sum(must.astype(jnp.int32)) \
+            + jnp.cumsum((cand & ~must).astype(jnp.int32)) - 1
+        prank = jnp.where(must, mrank, wrank)
+        slot_for = jnp.argsort(~free, stable=True)[jnp.clip(prank, 0, h - 1)]
+        ok = cand & (prank < n_free) & (prank < h)
 
-    s_ok = jnp.where(ok, slot_for, h)        # out-of-bounds scatter -> drop
-    v_ok = jnp.where(ok, gvert, n)
-    promo_rows = jnp.where(real[:, None], _tail_rows(tail_nbr[gv_ok], n, w),
-                           jnp.uint32(0))
-    hub_adj = hub_adj.at[s_ok].set(promo_rows)   # free slots hold zero rows
-    hub_ids = hub_ids.at[s_ok].set(v_ok)
-    hub_slot = hub_slot.at[v_ok].set(jnp.where(ok, slot_for, 0).astype(jnp.int32))
-    tail_nbr = tail_nbr.at[v_ok].set(jnp.int32(n))
+        s_ok = jnp.where(ok, slot_for, h)        # out-of-bounds scatter -> drop
+        v_ok = jnp.where(ok, gvert, n)
+        promo_rows = jnp.where(real[:, None], _tail_rows(tail_nbr[gv_ok], n, w),
+                               jnp.uint32(0))
+        hub_adj = hub_adj.at[s_ok].set(promo_rows)   # free slots hold zero rows
+        hub_ids = hub_ids.at[s_ok].set(v_ok)
+        hub_slot = hub_slot.at[v_ok].set(jnp.where(ok, slot_for, 0).astype(jnp.int32))
+        tail_nbr = tail_nbr.at[v_ok].set(jnp.int32(n))
 
-    # ---- insertion (hub rows get bits, tail buffers get sorted ids) ----
-    slot_now = jnp.where(liveo, hub_slot[jnp.clip(verts, 0, n - 1)], -1)
-    to_hub = liveo & (slot_now >= 0)
-    hbit = jnp.where(to_hub, jnp.uint32(1) << (others % 32).astype(jnp.uint32),
-                     jnp.uint32(0))
-    # live edges are deduped and absent from A, so the added bits are
-    # distinct and unset: add == bitwise-or (promoted rows included)
-    hub_adj = hub_adj.at[jnp.where(to_hub, slot_now, h),
-                         jnp.clip(others // 32, 0, w - 1)].add(hbit)
+        # ---- insertion (hub rows get bits, tail buffers get sorted ids) ----
+        slot_now = jnp.where(liveo, hub_slot[jnp.clip(verts, 0, n - 1)], -1)
+        to_hub = liveo & (slot_now >= 0)
+        hbit = jnp.where(to_hub, jnp.uint32(1) << (others % 32).astype(jnp.uint32),
+                         jnp.uint32(0))
+        # live edges are deduped and absent from A, so the added bits are
+        # distinct and unset: add == bitwise-or (promoted rows included)
+        hub_adj = hub_adj.at[jnp.where(to_hub, slot_now, h),
+                             jnp.clip(others // 32, 0, w - 1)].add(hbit)
 
-    to_tail = liveo & (slot_now < 0)
-    # arrival rank of each occurrence within its vertex's block segment
-    first_pos = jnp.full((big,), big, jnp.int32).at[lid_sorted].min(
-        jnp.arange(big, dtype=jnp.int32))
-    rank = jnp.zeros((big,), jnp.int32).at[order].set(
-        jnp.arange(big, dtype=jnp.int32) - first_pos[lid_sorted])
-    pos = jnp.where(liveo, deg[jnp.clip(verts, 0, n - 1)], 0) + rank
-    over = to_tail & (pos >= c)              # slot exhausted AND buffer full
-    tail_nbr = tail_nbr.at[jnp.where(to_tail & (pos < c), verts, n),
-                           jnp.clip(pos, 0, c - 1)].set(
-        jnp.where(to_tail, others, n))
-    lost = state["lost"] + jnp.sum(over.astype(jnp.int32))
+        to_tail = liveo & (slot_now < 0)
+        # arrival rank of each occurrence within its vertex's block segment
+        first_pos = jnp.full((big,), big, jnp.int32).at[lid_sorted].min(
+            jnp.arange(big, dtype=jnp.int32))
+        rank = jnp.zeros((big,), jnp.int32).at[order].set(
+            jnp.arange(big, dtype=jnp.int32) - first_pos[lid_sorted])
+        pos = jnp.where(liveo, deg[jnp.clip(verts, 0, n - 1)], 0) + rank
+        over = to_tail & (pos >= c)              # slot exhausted AND buffer full
+        tail_nbr = tail_nbr.at[jnp.where(to_tail & (pos < c), verts, n),
+                               jnp.clip(pos, 0, c - 1)].set(
+            jnp.where(to_tail, others, n))
+        lost = state["lost"] + jnp.sum(over.astype(jnp.int32))
 
-    # keep touched tail buffers sorted (sentinel n sorts past the fill):
-    # canonical layout -> bit-identical checkpoints regardless of feed order
-    still_tail = real & (hub_slot[gv_ok] < 0) & touched
-    resorted = jnp.sort(tail_nbr[gv_ok], axis=1)
-    tail_nbr = tail_nbr.at[jnp.where(still_tail, gvert, n)].set(resorted)
+        # keep touched tail buffers sorted (sentinel n sorts past the fill):
+        # canonical layout -> bit-identical checkpoints regardless of feed order
+        still_tail = real & (hub_slot[gv_ok] < 0) & touched
+        resorted = jnp.sort(tail_nbr[gv_ok], axis=1)
+        tail_nbr = tail_nbr.at[jnp.where(still_tail, gvert, n)].set(resorted)
 
-    deg = deg.at[jnp.where(liveo, verts, n)].add(1)
+        deg = deg.at[jnp.where(liveo, verts, n)].add(1)
     return {"hub_adj": hub_adj, "hub_ids": hub_ids, "hub_slot": hub_slot,
             "tail_nbr": tail_nbr, "deg": deg, "count": count, "lost": lost}
 

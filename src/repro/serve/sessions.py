@@ -84,6 +84,7 @@ import time
 import jax.numpy as jnp
 import numpy as np
 
+from repro.tracing import span
 from repro.utils import PropagatingThread, count_dtype
 
 # Epoch marker in a waiting session's host-side buffer: replayed as advance()
@@ -632,49 +633,51 @@ class StreamMultiplexer:
             raise ValueError(f"priority must be an int, got {priority!r}")
         if deadline_s is not None and not deadline_s > 0:
             raise ValueError(f"deadline_s must be > 0, got {deadline_s!r}")
-        self._reap()
-        # let live waiters claim any free budget (e.g. freed by an explicit
-        # preempt) before the fairness gate treats them as blocking
-        self._admit_pending()
-        sid = self._next_id
-        self._next_id += 1
-        rec = _Session(
-            sid=sid, n_nodes=int(n_nodes),
-            block_size=block_size if block_size is not None else self.block_size,
-            window=int(window) if window is not None else None,
-            priority=int(priority), deadline_s=deadline_s,
-            last_activity=self._clock())
-        # fairness gate: admit around the waiters only with strictly higher
-        # priority than every one of them (FIFO within a priority level;
-        # policy="fifo" never admits around any waiter). Parked sessions are
-        # deliberately benched — they don't block anyone.
-        blocking = any(
-            r.state != "active" and not r.parked
-            and (self.policy == "fifo" or r.priority >= rec.priority)
-            for r in self._recs.values())
-        if not blocking:
-            adm, victim_sids = self._admission(
-                rec.n_nodes, self.bytes_in_use, rec.window,
-                priority=rec.priority, preempt=self.policy == "fair")
-            if adm.admitted:
-                from repro.api.planner import BackpressureError
+        # the sid this open assigns: the next id, taken below
+        with span("mux.open", sid=self._next_id):
+            self._reap()
+            # let live waiters claim any free budget (e.g. freed by an explicit
+            # preempt) before the fairness gate treats them as blocking
+            self._admit_pending()
+            sid = self._next_id
+            self._next_id += 1
+            rec = _Session(
+                sid=sid, n_nodes=int(n_nodes),
+                block_size=block_size if block_size is not None else self.block_size,
+                window=int(window) if window is not None else None,
+                priority=int(priority), deadline_s=deadline_s,
+                last_activity=self._clock())
+            # fairness gate: admit around the waiters only with strictly higher
+            # priority than every one of them (FIFO within a priority level;
+            # policy="fifo" never admits around any waiter). Parked sessions are
+            # deliberately benched — they don't block anyone.
+            blocking = any(
+                r.state != "active" and not r.parked
+                and (self.policy == "fifo" or r.priority >= rec.priority)
+                for r in self._recs.values())
+            if not blocking:
+                adm, victim_sids = self._admission(
+                    rec.n_nodes, self.bytes_in_use, rec.window,
+                    priority=rec.priority, preempt=self.policy == "fair")
+                if adm.admitted:
+                    from repro.api.planner import BackpressureError
 
-                try:
-                    if victim_sids:
-                        self._preempt_many(victim_sids)
-                except BackpressureError:
-                    pass  # store full: can't park the victims — queue instead
-                else:
-                    self._recs[sid] = rec
-                    self._admit(rec, adm)
-                    return sid
-        idle, _ = self._admission(rec.n_nodes, 0, rec.window)
-        if not idle.admitted:
-            raise ValueError(
-                f"stream of {rec.n_nodes} nodes can never be admitted on "
-                f"this server: {idle.reason}")
-        self._recs[sid] = rec
-        return sid
+                    try:
+                        if victim_sids:
+                            self._preempt_many(victim_sids)
+                    except BackpressureError:
+                        pass  # store full: can't park the victims — queue instead
+                    else:
+                        self._recs[sid] = rec
+                        self._admit(rec, adm)
+                        return sid
+            idle, _ = self._admission(rec.n_nodes, 0, rec.window)
+            if not idle.admitted:
+                raise ValueError(
+                    f"stream of {rec.n_nodes} nodes can never be admitted on "
+                    f"this server: {idle.reason}")
+            self._recs[sid] = rec
+            return sid
 
     def feed(self, sid: int, edges) -> None:
         """Feed one (B, 2) edge array to session ``sid``: ingested through
@@ -684,35 +687,36 @@ class StreamMultiplexer:
         raising ``BackpressureError`` past it. Edge arrays are validated at
         this front door either way (shape (B, 2), integer dtype, ids in
         ``[0, n_nodes)``)."""
-        rec = self._rec(sid)
-        if rec.state == "active":
-            if rec.driver is not None:
+        with span("mux.feed", sid=sid):
+            rec = self._rec(sid)
+            if rec.state == "active":
+                if rec.driver is not None:
+                    from repro.core import streaming
+
+                    # validate HERE (front-door contract) so the producer thread
+                    # only ever sees clean arrays and errors raise in the caller
+                    rec.driver.submit(streaming.validate_edges(edges, rec.n_nodes))
+                else:
+                    rec.session.feed(edges)
+                rec.served_blocks += 1
+            else:
+                from repro.api.planner import BackpressureError
                 from repro.core import streaming
 
-                # validate HERE (front-door contract) so the producer thread
-                # only ever sees clean arrays and errors raise in the caller
-                rec.driver.submit(streaming.validate_edges(edges, rec.n_nodes))
-            else:
-                rec.session.feed(edges)
-            rec.served_blocks += 1
-        else:
-            from repro.api.planner import BackpressureError
-            from repro.core import streaming
-
-            arr = streaming.validate_edges(edges, rec.n_nodes)
-            if self.queue_bytes + arr.nbytes > self.queue_budget_bytes:
-                raise BackpressureError(
-                    f"waiting-session feed budget exhausted: {arr.nbytes} B "
-                    f"over {self.queue_bytes}/{self.queue_budget_bytes} B "
-                    f"already buffered across "
-                    f"{self.n_queued + self.n_preempted} waiting session(s) "
-                    f"— close an active session (or raise "
-                    f"queue_budget_bytes)")
-            rec.blocks.append(arr)
-            rec.buffered_bytes += arr.nbytes
-            self.queue_bytes += arr.nbytes
-            rec.parked = False  # new activity: rejoin the readmission pool
-        rec.last_activity = self._clock()
+                arr = streaming.validate_edges(edges, rec.n_nodes)
+                if self.queue_bytes + arr.nbytes > self.queue_budget_bytes:
+                    raise BackpressureError(
+                        f"waiting-session feed budget exhausted: {arr.nbytes} B "
+                        f"over {self.queue_bytes}/{self.queue_budget_bytes} B "
+                        f"already buffered across "
+                        f"{self.n_queued + self.n_preempted} waiting session(s) "
+                        f"— close an active session (or raise "
+                        f"queue_budget_bytes)")
+                rec.blocks.append(arr)
+                rec.buffered_bytes += arr.nbytes
+                self.queue_bytes += arr.nbytes
+                rec.parked = False  # new activity: rejoin the readmission pool
+            rec.last_activity = self._clock()
 
     def advance(self, sid: int) -> None:
         """Slide session ``sid``'s window one epoch (windowed sessions only:
@@ -845,44 +849,45 @@ class StreamMultiplexer:
         priority actives if that is what it takes), and if the device cannot
         host that restore the close raises ``BackpressureError`` and the
         session stays parked."""
-        if sid in self._results:
-            return self._results[sid]
-        if sid not in self._recs:
-            raise KeyError(f"unknown session {sid}")
-        self._reap()
-        if sid in self._results:  # the reap just expired it
-            return self._results[sid]
-        rec = self._recs[sid]
-        if rec.state != "active":
-            self._admit_pending()
-        if rec.state == "preempted" and not rec.blocks:
-            # nothing fed since the checkpoint: the count is already in the
-            # host snapshot — finalize without touching the device
-            result = self.store.take(sid).finalize_result()
-            result.stats["priority"] = rec.priority
-            result.stats["preempts"] = rec.n_preempts
-            result.stats["restored"] = False
-            del self._recs[sid]
-            self._results[sid] = result
+        with span("mux.close", sid=sid):
+            if sid in self._results:
+                return self._results[sid]
+            if sid not in self._recs:
+                raise KeyError(f"unknown session {sid}")
+            self._reap()
+            if sid in self._results:  # the reap just expired it
+                return self._results[sid]
+            rec = self._recs[sid]
+            if rec.state != "active":
+                self._admit_pending()
+            if rec.state == "preempted" and not rec.blocks:
+                # nothing fed since the checkpoint: the count is already in the
+                # host snapshot — finalize without touching the device
+                result = self.store.take(sid).finalize_result()
+                result.stats["priority"] = rec.priority
+                result.stats["preempts"] = rec.n_preempts
+                result.stats["restored"] = False
+                del self._recs[sid]
+                self._results[sid] = result
+                self._admit_pending()
+                return result
+            if rec.state == "preempted":
+                self._force_restore(rec)
+            if rec.state == "queued":
+                self._sched["cancellations"] += 1
+                result = self._cancel(rec)
+            else:
+                self._quiesce(rec)
+                session = rec.session
+                result = session.finalize()
+                self.bytes_in_use -= rec.state_bytes
+                result.stats["priority"] = rec.priority
+                result.stats["preempts"] = rec.n_preempts
+                result.stats["restored"] = session.restored
+                del self._recs[sid]
+                self._results[sid] = result
             self._admit_pending()
             return result
-        if rec.state == "preempted":
-            self._force_restore(rec)
-        if rec.state == "queued":
-            self._sched["cancellations"] += 1
-            result = self._cancel(rec)
-        else:
-            self._quiesce(rec)
-            session = rec.session
-            result = session.finalize()
-            self.bytes_in_use -= rec.state_bytes
-            result.stats["priority"] = rec.priority
-            result.stats["preempts"] = rec.n_preempts
-            result.stats["restored"] = session.restored
-            del self._recs[sid]
-            self._results[sid] = result
-        self._admit_pending()
-        return result
 
     def kill(self, sid: int):
         """SIGKILL analogue: tear session ``sid`` down NOW, without draining.

@@ -163,7 +163,10 @@ class ShardedStateStream:
         replicated, and the returned carry must already be identical across
         stages (psum inside the step). Memoized per step function so repeated
         blocks of one stream reuse one compiled executable, which is named
-        after the step (``jit_<step_fn.__name__>``)."""
+        after the step (``jit_<step_fn.__name__>``). The state is DONATED:
+        each shard is written in place, so the caller rebinds
+        (``state, carry = step(state, carry, block)``) and never touches the
+        old state again."""
         if step_fn not in self._jit_cache:
             ax = self.axis_name
 
@@ -182,7 +185,7 @@ class ShardedStateStream:
                 out_specs=(P(ax), P()),
                 check_vma=False,
             )
-            self._jit_cache[step_fn] = jax.jit(sharded)
+            self._jit_cache[step_fn] = jax.jit(sharded, donate_argnums=(0,))
         return self._jit_cache[step_fn]
 
 

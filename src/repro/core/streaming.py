@@ -15,8 +15,12 @@ Two ingest implementations share that contract:
   D: Σ_e pc(A[u]&D[v]) + pc(D[u]&A[v]) counts each (block, block, A) triangle
   twice and Σ_e pc(D[u]&D[v]) counts each all-in-block triangle three times,
   so the block's contribution is ``pre + mixed//2 + dd//3`` (A and D are
-  disjoint by dedup, so the terms never overlap). All insertions land in one
-  scatter. No per-edge sequential dependency remains.
+  disjoint by dedup, so the terms never overlap). The block is written into
+  the donated state IN PLACE: A's rows are gathered, the 2·B live bits are
+  added as one scatter of one-hot 128-word windows (``_block_bits``), and
+  the same rows gathered again give ``D[u] = new[u] ^ A[u]``. No (n, W)
+  delta table is built, so a block moves ~16·B·W bytes of rows whatever n
+  is. No per-edge sequential dependency remains.
 - ``ingest_block_per_edge`` — the seed per-edge ``lax.scan`` fold, RETAINED AS
   THE DIFFERENTIAL ORACLE (and the BENCH_kernels.json ``stream_bench``
   baseline): O(B) sequential steps per block, trivially correct.
@@ -78,6 +82,9 @@ from repro.utils import count_dtype
 # allocation. Mirrors triangle_pipeline's bitset-ring gating.
 _MASK_VMEM_BUDGET = 8 * 1024 * 1024
 _EDGE_SMEM_BUDGET = 256 * 1024
+
+# uint32 words in one row of an (8, 128) tile, the TPU's layout of a table
+_LANES = 128
 
 
 def init_state(n_nodes: int) -> dict:
@@ -277,26 +284,51 @@ def _stage_seen(adj_s: jax.Array, lo: jax.Array, hi: jax.Array, off) -> jax.Arra
         return jnp.where(owned, bit, jnp.uint32(0))
 
 
-def _delta_scatter(n: int, ws: int, lo: jax.Array, hi: jax.Array,
-                   live: jax.Array, off) -> jax.Array:
-    """The block's delta-adjacency on this stage's word shard: every live
-    edge's two bits, landed in ONE scatter (dead edges scatter out of bounds
-    and are dropped)."""
-
-    def owned_scatter(dst, row, col_node):
-        wl = col_node // 32 - off
-        ok = live & (wl >= 0) & (wl < ws)
-        r = jnp.where(ok, row, n)  # out-of-bounds scatter index -> dropped
-        c = jnp.where(ok, wl, 0)
-        bit = jnp.where(ok, jnp.uint32(1) << (col_node % 32).astype(jnp.uint32),
-                        jnp.uint32(0))
-        # dedup guarantees each (row, col_node) appears once, so distinct
-        # updates to one word carry distinct bits and add == bitwise-or
-        return dst.at[r, c].add(bit)
-
+def _block_bits(n: int, ws: int, lo: jax.Array, hi: jax.Array,
+                live: jax.Array, off) -> tuple[jax.Array, jax.Array]:
+    """The block's delta-adjacency on this stage's word shard as 2B one-hot
+    windows for :func:`_write_bits`: ``(at, win)`` with ``win[i]`` the L
+    words holding one edge's bit and ``at[i]`` the lane row it is added to.
+    A table of whole (8, 128) tiles, the TPU's layout for it, has L = 128
+    and lane rows in the order it is stored in: tile by tile, then sublane
+    by sublane. Any other table has its whole rows as lane rows (L = W_s).
+    The ``lo→hi`` bits come first, then ``hi→lo``. Dead edges and bits of
+    words another stage owns get ``at`` = n·W_s/L, one past the last lane
+    row, so the scatter drops them. The windows are a broadcast compare: no
+    (n, W_s) table is built."""
     with jax.named_scope(INGEST_UPDATE):
-        delta = owned_scatter(jnp.zeros((n, ws), jnp.uint32), lo, hi)
-        return owned_scatter(delta, hi, lo)
+        row = jnp.concatenate([lo, hi])
+        col = jnp.concatenate([hi, lo])
+        wl = col // 32 - off
+        ok = jnp.concatenate([live, live]) & (wl >= 0) & (wl < ws)
+        lanes = _LANES if n % 8 == 0 and ws % _LANES == 0 else ws
+        # tile (row // 8, wl // lanes), sublane row % 8; whole rows: at = row
+        at = ((row // 8) * (ws // lanes) + wl // lanes) * 8 + row % 8
+        bit = jnp.uint32(1) << (col % 32).astype(jnp.uint32)
+        win = jnp.where(
+            ok[:, None] & (jnp.arange(lanes, dtype=wl.dtype)[None, :]
+                           == (wl % lanes)[:, None]),
+            bit[:, None], jnp.uint32(0))
+        return jnp.where(ok, at, n * ws // lanes), win
+
+
+def _write_bits(table: jax.Array, at: jax.Array, win: jax.Array) -> jax.Array:
+    """Add :func:`_block_bits`' windows into ``table`` (..., n, W_s) in one
+    scatter over its lane rows, dropping rows out of bounds. On the TPU the
+    lane-row view of a tiled table is a bitcast, so the write is in place
+    and moves 512 bytes an update. The bits are distinct and unset in
+    ``table``: ``add`` is ``or``."""
+    *lead, n, ws = table.shape
+    lanes = win.shape[1]
+    with jax.named_scope(INGEST_UPDATE):
+        if lanes == ws:
+            rows = table.reshape(-1, ws).at[at].add(win, mode="drop")
+            return rows.reshape(table.shape)
+        tiles = table.reshape(*lead, n // 8, 8, ws // lanes, lanes)
+        rows = jnp.swapaxes(tiles, -3, -2).reshape(-1, lanes)
+        rows = rows.at[at].add(win, mode="drop")
+        tiles = rows.reshape(*lead, n // 8, ws // lanes, 8, lanes)
+        return jnp.swapaxes(tiles, -3, -2).reshape(table.shape)
 
 
 def _kernel_fits(use_kernel: bool, table_bytes: int, n_edges: int) -> bool:
@@ -324,7 +356,6 @@ def _stage_update(adj_s: jax.Array, lo: jax.Array, hi: jax.Array,
     triangle three times, and those multiplicities only hold for the
     full-width sums."""
     n, ws = adj_s.shape
-    delta = _delta_scatter(n, ws, lo, hi, live, off)
 
     def masked_sum(words):
         pc = jax.lax.population_count(words).sum(axis=-1)
@@ -334,31 +365,34 @@ def _stage_update(adj_s: jax.Array, lo: jax.Array, hi: jax.Array,
         glo = jnp.clip(lo, 0, n - 1)
         ghi = jnp.clip(hi, 0, n - 1)
         au, av = adj_s[glo], adj_s[ghi]
-        du, dv = delta[glo], delta[ghi]
+    at, win = _block_bits(n, ws, lo, hi, live, off)
+    new = _write_bits(adj_s, at, win)  # live bits are unset in A (seen)
 
+    with jax.named_scope(INGEST_TERMS):
         table_bytes = n * ws * 4
-        if _kernel_fits(use_kernel, table_bytes, lo.shape[0]):
+        kernel = _kernel_fits(use_kernel, table_bytes, lo.shape[0])
+        if kernel and 2 * table_bytes <= _MASK_VMEM_BUDGET:
+            # the pair kernel holds two whole tables: give it D as one
             from repro.kernels.bitset_count.ops import bitset_edge_count, bitset_pair_count
 
+            delta = _write_bits(jnp.zeros_like(adj_s), at, win)
             ek = _phantom_edges(lo, hi, live, n)
             pre = bitset_edge_count(adj_s, ek)
-            if 2 * table_bytes <= _MASK_VMEM_BUDGET:  # pair kernel holds two tables
-                mixed = (bitset_pair_count(adj_s, delta, ek)
-                         + bitset_pair_count(delta, adj_s, ek))
-                dd = bitset_edge_count(delta, ek)
-            else:
-                mixed = masked_sum(au & dv) + masked_sum(du & av)
-                dd = masked_sum(du & dv)
+            mixed = (bitset_pair_count(adj_s, delta, ek)
+                     + bitset_pair_count(delta, adj_s, ek))
+            dd = bitset_edge_count(delta, ek)
         else:
-            pre = masked_sum(au & av)
+            # A and D are disjoint, so the written rows XOR the old are D's
+            du, dv = new[glo] ^ au, new[ghi] ^ av
+            if kernel:
+                from repro.kernels.bitset_count.ops import bitset_edge_count
+
+                pre = bitset_edge_count(adj_s, _phantom_edges(lo, hi, live, n))
+            else:
+                pre = masked_sum(au & av)
             mixed = masked_sum(au & dv) + masked_sum(du & av)
             dd = masked_sum(du & dv)
-    # the state write sits between the sums and their stack, in the order
-    # the ops were always traced: the scopes leave the compiled HLO as it was
-    with jax.named_scope(INGEST_UPDATE):
-        adj_s = adj_s | delta
-    with jax.named_scope(INGEST_TERMS):
-        return adj_s, jnp.stack([pre, mixed, dd])
+        return new, jnp.stack([pre, mixed, dd])
 
 
 def _combine(count, terms):
@@ -410,7 +444,6 @@ def _windowed_stage_update(epochs_s: jax.Array, cum: jax.Array,
     (``_windowed_combine``) — multiplicities only hold for full-width sums,
     exactly like the unbounded path."""
     n_epochs, n, ws = epochs_s.shape
-    delta = _delta_scatter(n, ws, lo, hi, live, off)
 
     def masked_sum(words):
         # words: (..., B, ws) -> (...,) masked popcount over live edges
@@ -420,14 +453,24 @@ def _windowed_stage_update(epochs_s: jax.Array, cum: jax.Array,
     with jax.named_scope(INGEST_TERMS):
         glo = jnp.clip(lo, 0, n - 1)
         ghi = jnp.clip(hi, 0, n - 1)
-        du, dv = delta[glo], delta[ghi]             # (B, ws)
+    at, win = _block_bits(n, ws, lo, hi, live, off)
+    # into slot head; live bits are unset in every slot (seen is the live OR)
+    per_slot = n * ws // win.shape[1]
+    new = _write_bits(epochs_s, jnp.where(at < per_slot, head * per_slot + at,
+                                          n_epochs * per_slot), win)
 
+    with jax.named_scope(INGEST_TERMS):
         table_bytes = n * ws * 4
         if _kernel_fits(use_kernel, table_bytes, lo.shape[0]):
             from repro.kernels.bitset_count.ops import bitset_edge_count, bitset_pair_count
 
             ek = _phantom_edges(lo, hi, live, n)
             pair_ok = 2 * table_bytes <= _MASK_VMEM_BUDGET
+            if pair_ok:  # the pair kernel holds two whole tables: D as one
+                delta = _write_bits(jnp.zeros((n, ws), jnp.uint32), at, win)
+            else:  # cum[0] is the head slot before the write
+                du = new[head, glo] ^ cum[0][glo]
+                dv = new[head, ghi] ^ cum[0][ghi]
             ps, ms = [], []
             for t in range(n_epochs):  # the unbounded kernels, once per epoch age
                 ps.append(bitset_edge_count(cum[t], ek))
@@ -439,16 +482,15 @@ def _windowed_stage_update(epochs_s: jax.Array, cum: jax.Array,
                     ms.append(masked_sum(cu & dv) + masked_sum(du & cv))
             p_terms = jnp.stack(ps)
             m_terms = jnp.stack(ms)
-            dd = bitset_edge_count(delta, ek)
+            dd = bitset_edge_count(delta, ek) if pair_ok else masked_sum(du & dv)
         else:
             cu, cv = cum[:, glo], cum[:, ghi]       # (E, B, ws)
+            # the head slot and D are disjoint, and cu[0] / cv[0] are the
+            # head rows before the write: after XOR before is D's rows
+            du, dv = new[head, glo] ^ cu[0], new[head, ghi] ^ cv[0]
             p_terms = masked_sum(cu & cv)           # (E,)
             m_terms = masked_sum(cu & dv[None]) + masked_sum(du[None] & cv)
             dd = masked_sum(du & dv)
-    # in the traced order, as in ``_stage_update``
-    with jax.named_scope(INGEST_UPDATE):
-        new = epochs_s.at[head].set(epochs_s[head] | delta)
-    with jax.named_scope(INGEST_TERMS):
         return new, jnp.concatenate([p_terms, m_terms, dd[None]])
 
 
@@ -485,8 +527,9 @@ def _ingest_block_impl(state: dict, edges: jax.Array, *,
     two-phase blocked ingest. Duplicate edges are ignored (the paper's
     simple-graph precondition); self-loops contribute nothing.
 
-    State bytes: the n²/8 ``adj`` bitset, updated in place-shape (transient
-    block working set ~8 gathered word-rows per edge). Trace contract: one
+    State bytes: the n²/8 ``adj`` bitset, written in place when donated
+    (transient block working set: four gathered word-rows and two one-hot
+    128-word windows per edge). Trace contract: one
     trace per (block shape, n, backend flags) — module-level jit, so every
     stream and session sharing a block shape shares ONE trace
     (``ingest_trace_count`` telemetry). ``ingest_block_donated`` is the same
@@ -552,7 +595,9 @@ def make_mesh_ingest(mesh, axis_name: str | None = None, *,
     interleaved serving sessions — on one mesh reuses one compiled
     executable: one trace per (block shape, mesh, backend flags). State
     bytes: n²/8/S per device — the real per-stage discount the admission
-    accounting may charge."""
+    accounting may charge. The state is DONATED, so each shard is written
+    in place: the caller rebinds (``state = ingest(state, block)``) and
+    never touches the old dict again."""
     from repro.core.dynamic_pipeline import ShardedStateStream
 
     runtime = ShardedStateStream.shared(mesh, axis_name or mesh.axis_names[0])
@@ -668,7 +713,8 @@ def make_mesh_ingest_windowed(mesh, axis_name: str | None = None, *,
     replicated carry; ``seen`` and the (P, M, dd) partials are psum-reduced
     per block before ``_windowed_combine``. Memoized per
     (mesh, axis, backend flags): every windowed stream on one mesh reuses
-    one compiled executable per block shape."""
+    one compiled executable per block shape. The ring is DONATED and
+    written in place, as in ``make_mesh_ingest``."""
     from repro.core.dynamic_pipeline import ShardedStateStream
 
     runtime = ShardedStateStream.shared(mesh, axis_name or mesh.axis_names[0])

@@ -119,3 +119,63 @@ def test_mesh_ingest_compiles_on_four_chips(topo):
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes < 2.2e9  # one shard, not the whole state
     assert "all-reduce" in compiled.as_text()
+
+
+# The delta-table ingest (a full (n, W_s) table zero-filled, scattered into
+# and ORed into the state every block) needed this much temp for the
+# windowed cell; the in-place write must come in under it less one table.
+_WINDOWED_DELTA_TABLE_TEMP = 6_039_549_440
+
+
+def _in_place_cell(layout, topo):
+    """(compiled donated step, n, W_s, state bytes, temp limit) at the size
+    of the benchmark cell that runs ``layout``."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    if layout == "dense":  # s16-tenants8
+        n, b = 65_536, 16_384
+        state = _shapes(jax.eval_shape(lambda: streaming.init_state(n)), one_chip)
+        edges = jax.ShapeDtypeStruct((b, 2), jnp.int32, sharding=one_chip)
+        compiled = streaming.ingest_block_donated.lower(state, edges).compile()
+        ws = n // 32
+        return compiled, n, ws, n * ws * 4, 16 * b * ws + 64 * 2**20
+    if layout == "windowed":  # s16-window4
+        n, b, e = 65_536, 4_096, 4
+        state = _shapes(jax.eval_shape(
+            lambda: streaming.init_windowed_state(n, e)), one_chip)
+        edges = jax.ShapeDtypeStruct((b, 2), jnp.int32, sharding=one_chip)
+        compiled = streaming.ingest_block_windowed_donated.lower(
+            state, edges).compile()
+        ws = n // 32
+        return compiled, n, ws, e * n * ws * 4, \
+            _WINDOWED_DELTA_TABLE_TEMP - n * ws * 4
+    # mesh, s18-ring4-sessions: one 2.15 GB shard a chip
+    mesh = Mesh(np.asarray(topo.devices), ("stage",))
+    n, b = 262_144, 16_384
+    shape = jax.eval_shape(lambda: streaming.init_sharded_state(n, 4))
+    ws = shape["adj"].shape[2]
+    state = {
+        "adj": jax.ShapeDtypeStruct(shape["adj"].shape, jnp.uint32,
+                                    sharding=NamedSharding(mesh, P("stage"))),
+        "count": jax.ShapeDtypeStruct((), shape["count"].dtype,
+                                      sharding=NamedSharding(mesh, P())),
+    }
+    edges = jax.ShapeDtypeStruct((b, 2), jnp.int32,
+                                 sharding=NamedSharding(mesh, P()))
+    # the step donates its shard; an outer donating jit compiles the same
+    # program (tests/test_state_write.py pins the step's own donation)
+    ingest = streaming.make_mesh_ingest(mesh)
+    compiled = jax.jit(ingest, donate_argnums=(0,)).lower(state, edges).compile()
+    return compiled, n, ws, n * ws * 4, 16 * b * ws + 64 * 2**20
+
+
+@pytest.mark.parametrize("layout", ["dense", "mesh", "windowed"])
+def test_ingest_writes_the_state_in_place(topo, layout):
+    """At each stream cell's size the block is written into the donated
+    state: the whole state aliases the output, the temp holds the row
+    gathers and no (n, W_s) delta table, and no op lays a table out as a
+    flat u32[n·W_s] array (the relayout the element scatter needed)."""
+    compiled, n, ws, state_bytes, limit = _in_place_cell(layout, topo)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= state_bytes, mem
+    assert mem.temp_size_in_bytes <= limit, (mem.temp_size_in_bytes, limit)
+    assert f"u32[{n * ws}]" not in compiled.as_text()

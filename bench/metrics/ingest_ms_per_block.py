@@ -5,9 +5,11 @@ one block. Layer: stream ingest core. Source: device trace."""
 
 # The XLA module names of the ingest executables (``core/streaming.py``):
 # ``jit__ingest_block_impl`` and ``jit__ingest_block_windowed_impl`` on one
-# chip; on a mesh the ingest step is jitted by
-# ``dynamic_pipeline.ShardedStateStream.jit_step`` as ``jit_stage_fn``, the
-# only shard_map executable a stream cell runs.
+# chip; on a mesh the ingest steps are jitted by
+# ``dynamic_pipeline.ShardedStateStream.jit_step`` as
+# ``jit_ingest_block_mesh`` and ``jit_ingest_block_windowed_mesh``, the only
+# shard_map executables a stream cell runs (``jit_stage_fn`` before the
+# program named its steps).
 INGEST = r"ingest|^jit_stage_fn$"
 
 

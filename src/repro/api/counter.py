@@ -171,6 +171,10 @@ class SessionCheckpoint:
         if p.window_epochs:
             stats["window_epochs"] = p.window_epochs
             stats["epochs_advanced"] = self.n_epochs_advanced
+        if p.state_layout == "hybrid":
+            from repro.core.streaming import hybrid_stats
+
+            stats["hybrid"] = hybrid_stats(arrays)
         return CountResult(count=count, plan=p, wall_s=self.wall_s,
                            stats=stats)
 
@@ -1016,6 +1020,9 @@ class StreamSession:
         if p.window_epochs:
             stats["window_epochs"] = p.window_epochs
             stats["epochs_advanced"] = self.n_epochs_advanced
+        if p.state_layout == "hybrid":
+            # lost, hub slots in use, promotions, live edges by class
+            stats["hybrid"] = streaming.hybrid_stats(self.state)
         self.result = CountResult(count=count, plan=p, wall_s=self._wall,
                                   stats=stats)
         return self.result

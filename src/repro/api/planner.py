@@ -55,14 +55,15 @@ _STREAM_PENALTY = 3.0
 _STREAM_BLOCK_MIN = 4096
 _STREAM_BLOCK_MAX = 1 << 20
 _STREAM_BLOCK_MEM_FRACTION = 8
-# Hybrid (degree-aware) state sizing: tail buffers hold this many neighbor
-# slots per vertex (clamped around 8x the average degree when stats are
-# informative), hub rows start at this floor and grow to the memory budget.
-# Hybrid blocks are much smaller than bitset blocks because the block-local
-# phase-2 working set is O(B^2) int32, not O(B·W).
+# Hybrid (degree-aware) state sizing: tail buffers hold between these many
+# neighbor slots per vertex (around 8x the average degree when stats are
+# informative); with stream-only stats the tail buffers take at most
+# 1/_HYBRID_TAIL_SHARE and the hub rows 1/_HYBRID_HUB_SHARE of the device.
+# Hybrid blocks are much smaller than bitset blocks.
 _HYBRID_TAIL_MIN = 16
 _HYBRID_TAIL_MAX = 1024
-_HYBRID_TAIL_DEFAULT = 64
+_HYBRID_TAIL_SHARE = 6
+_HYBRID_HUB_SHARE = 8
 _HYBRID_HUB_MIN = 64
 _HYBRID_BLOCK_MIN = 128
 _HYBRID_BLOCK_MAX = 8192
@@ -71,6 +72,11 @@ _HYBRID_BLOCK_MAX = 8192
 def _pow2_at_least(x: int) -> int:
     """Smallest power of two >= max(x, 1)."""
     return 1 << max(int(x) - 1, 0).bit_length()
+
+
+def _pow2_at_most(x: int) -> int:
+    """Largest power of two <= max(x, 1)."""
+    return 1 << (max(int(x), 1).bit_length() - 1)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -318,39 +324,94 @@ def hybrid_sizing(stats: GraphStats, res: Resources) -> HybridSizing | None:
     With informative stats (``n_edges > 0``) the tail capacity is ~8x the
     average degree (power-law tails sit far below the mean, hubs far above —
     the promotion threshold catches the latter) and hub slots cover ~4x the
-    vertices a uniform spread would need at that capacity. With stream-only
-    stats (``n_edges == 0``) the tail defaults to ``_HYBRID_TAIL_DEFAULT``
-    neighbors and hub slots grow from ``_HYBRID_HUB_MIN`` toward a quarter
-    of the memory budget — admission cannot see degrees, so it buys as much
-    promotion headroom as the budget allows. The block size keeps the
-    block-local phase-2 working set (~16·B² bytes of packed int32 plus
-    gathered rows) within a quarter of the budget."""
+    vertices a uniform spread would need at that capacity.
+
+    With stream-only stats (``n_edges == 0``, what admission sees) the
+    sizes follow from n and ``res.memory_bytes`` alone: the tail capacity C
+    is the largest power of two whose n·C·4 bytes of buffers take at most a
+    sixth of the budget (clamped to [16, 1024]), the hub slots H the largest
+    power of two whose H·⌈n/32⌉·4 bytes of rows take at most an eighth
+    (at least 64, at most n rounded up), and a tail vertex is promoted when
+    its degree reaches C. At n = 2²⁰ on one v5e (15.75 GiB) that is C = 512
+    and H = 8,192: 1.07 GB of hub rows and 2.16 GB of tail buffers, 3.2 GB a
+    session. A Graph500 scale-20 graph (edge factor 16) has 6,196 vertices
+    of degree ≥ 512 (seed 123, ``bench/gen/rmat.py``; the Kronecker degrees
+    are lumpy and none falls between 512 and 1,024), so the slots hold its
+    hubs with 32% headroom, and two such sessions take 6.5 GB.
+
+    The block size B is the largest power of two in [128, 8192] whose
+    ingest working set (:func:`hybrid_block_bytes`) takes at most a
+    sixteenth of the budget (1 MiB at least): B = 2,048 at n = 2²⁰ on one
+    v5e, 690 MB against 1.06 GB (4,096 would need 1.65 GB)."""
     n = max(stats.n_nodes, 1)
     w = -(-n // 32)
     n_cap = _pow2_at_least(n)
-    budget = max(res.memory_bytes // 4, 1)
     if stats.n_edges > 0:
         avg = max(1, (2 * stats.n_edges) // n)
         cap = min(max(_pow2_at_least(8 * avg), _HYBRID_TAIL_MIN), _HYBRID_TAIL_MAX)
         hubs = min(max(_pow2_at_least(4 * stats.n_edges // cap + 1),
                        _HYBRID_HUB_MIN), n_cap)
     else:
-        cap = _HYBRID_TAIL_DEFAULT
-        hubs = _HYBRID_HUB_MIN
-        while hubs * 2 <= n_cap and (hubs * 2) * w * 4 * 2 <= budget:
-            hubs *= 2
+        cap = min(max(_pow2_at_most(res.memory_bytes // _HYBRID_TAIL_SHARE // (4 * n)),
+                      _HYBRID_TAIL_MIN), _HYBRID_TAIL_MAX)
+        hubs = min(max(_pow2_at_most(res.memory_bytes // _HYBRID_HUB_SHARE // (4 * w)),
+                       _HYBRID_HUB_MIN), n_cap)
     from repro.core.streaming import hybrid_state_nbytes
 
     nbytes = hybrid_state_nbytes(n, hubs, cap)
     if nbytes >= 4 * n * w:  # dense bitset is no bigger: hybrid buys nothing
         return None
-    block_budget = max(res.memory_bytes // 4, 1 << 20)
+    block_budget = max(res.memory_bytes // 16, 1 << 20)
     block = _HYBRID_BLOCK_MIN
     while (block < _HYBRID_BLOCK_MAX
-           and 2 * (32 * block * w + 16 * block * block) <= block_budget):
+           and hybrid_block_bytes(2 * block, n, cap) <= block_budget):
         block *= 2
     return HybridSizing(hub_slots=hubs, tail_capacity=cap, hub_threshold=cap,
                         state_bytes=nbytes, block_size=block)
+
+
+def hybrid_block_bytes(block: int, n_nodes: int, tail_capacity: int) -> int:
+    """The block-sized working set of one hybrid ingest block of ``block``
+    edges: two hub rows gathered per edge, were every edge hub–hub
+    (8·B·⌈n/32⌉); the (2B, C) lists of the block's endpoints and the (B, 2C)
+    merge of two lists per edge (16·B·C); phase 2's widest window, the
+    int32 positions and partners of (2B, 2B) occurrence pairs, and the
+    block-local (2B, 2B) bitsets (32·B² + B²/2). The compiled ingest's temp
+    stays within it (``tests/test_tpu_compile.py``)."""
+    w = -(-n_nodes // 32)
+    return (8 * block * w + 16 * block * tail_capacity
+            + 32 * block * block + block * block // 2)
+
+
+def _session_hybrid(stats: GraphStats, res: Resources,
+                    remaining: int) -> HybridSizing | None:
+    """The hybrid sizing of one more session: sized against the whole
+    device, so that every session of n vertices on it gets the same shapes
+    (one compile) for as long as that state fits the ``remaining`` budget;
+    sized against the remainder only when it does not."""
+    whole = hybrid_sizing(stats, res)
+    if whole is not None and whole.state_bytes <= remaining:
+        return whole
+    return hybrid_sizing(stats, dataclasses.replace(res, memory_bytes=remaining))
+
+
+def _hybrid_plan(hyb: HybridSizing, stats: GraphStats, res: Resources,
+                 shard_bytes: int) -> Plan:
+    """The stream plan that runs the hybrid state ``hyb``."""
+    _, cost = _predict(stats, res, "stream", 1)
+    return Plan(
+        method="stream", n_stages=1, block_size=hyb.block_size,
+        state_layout="hybrid", hub_slots=hyb.hub_slots,
+        tail_capacity=hyb.tail_capacity, hub_threshold=hyb.hub_threshold,
+        predicted_bytes=hyb.state_bytes, predicted_cost=cost,
+        **backend_exec_flags(res),
+        reason=(f"edges not memory-resident -> degree-aware hybrid "
+                f"streaming state ({hyb.hub_slots} hub bitset rows + "
+                f"{hyb.tail_capacity}-slot tail buffers, "
+                f"{hyb.state_bytes} B vs {shard_bytes} B bitset shard)"
+                + ("" if hyb.state_bytes <= res.memory_bytes else
+                   " (WARNING: even the hybrid state exceeds the "
+                   "memory budget)")))
 
 
 def backend_exec_flags(res: Resources) -> dict:
@@ -412,21 +473,7 @@ def plan(stats: GraphStats, resources: Resources | None = None, *,
         hyb = None if window_epochs else hybrid_sizing(stats, res)
         if hyb is not None and (not fits or (stats.n_edges > 0
                                              and hyb.state_bytes < shard_bytes)):
-            hyb_fits = hyb.state_bytes <= res.memory_bytes
-            return Plan(
-                method="stream", n_stages=1, block_size=hyb.block_size,
-                state_layout="hybrid", hub_slots=hyb.hub_slots,
-                tail_capacity=hyb.tail_capacity, hub_threshold=hyb.hub_threshold,
-                predicted_bytes=hyb.state_bytes, predicted_cost=cost,
-                **backend_exec_flags(res),
-                reason=(f"edges not memory-resident -> degree-aware hybrid "
-                        f"streaming state ({hyb.hub_slots} hub bitset rows + "
-                        f"{hyb.tail_capacity}-slot tail buffers, "
-                        f"{hyb.state_bytes} B vs {shard_bytes} B bitset shard)"
-                        + ("" if hyb_fits else
-                           " (WARNING: even the hybrid state exceeds the "
-                           "memory budget)")),
-            )
+            return _hybrid_plan(hyb, stats, res, shard_bytes)
         shape = (f"ring-sharded ({n_stages} stages, ~{shard_bytes >> 20} MB/stage) "
                  if n_stages > 1 else "")
         window = (f"windowed ({window_epochs}-epoch ring) " if window_epochs else "")
@@ -603,11 +650,11 @@ def admit_session(n_nodes: int, resources: Resources | None = None, *,
         # linear-in-n hybrid state may still fit — admit it honestly before
         # resorting to preemption. plan(stats, sub) picks hybrid by the same
         # rule (bitset does not fit sub), so plan and charge stay consistent.
-        hyb = None if window_epochs else hybrid_sizing(stats, sub)
+        hyb = None if window_epochs else _session_hybrid(stats, res, remaining)
         if hyb is not None and hyb.state_bytes <= remaining:
             return _stamp(Admission(
                 action="admit-hybrid",
-                plan=plan(stats, sub, window_epochs=window_epochs),
+                plan=_hybrid_plan(hyb, stats, sub, shard_bytes),
                 state_bytes=hyb.state_bytes,
                 reason=(f"admit-hybrid: bitset shard needs {shard_bytes} B "
                         f"but the degree-aware hybrid state "
@@ -629,16 +676,19 @@ def admit_session(n_nodes: int, resources: Resources | None = None, *,
             sub_k = dataclasses.replace(res, memory_bytes=remaining + freed)
             n_stages, _, shard_bytes = stream_sizing(
                 stats, sub_k, window_epochs=window_epochs)
-            hyb_k = None if window_epochs else hybrid_sizing(stats, sub_k)
-            fit_bytes = None
+            hyb_k = (None if window_epochs
+                     else _session_hybrid(stats, res, remaining + freed))
+            fit_bytes = fit_plan = None
             if shard_bytes <= remaining + freed:
                 fit_bytes = shard_bytes
+                fit_plan = plan(stats, sub_k, window_epochs=window_epochs)
             elif hyb_k is not None and hyb_k.state_bytes <= remaining + freed:
                 fit_bytes = hyb_k.state_bytes
+                fit_plan = _hybrid_plan(hyb_k, stats, sub_k, shard_bytes)
             if fit_bytes is not None:
                 return _stamp(Admission(
                     action="preempt",
-                    plan=plan(stats, sub_k, window_epochs=window_epochs),
+                    plan=fit_plan,
                     state_bytes=fit_bytes, victims=tuple(victims),
                     reason=(f"preempt: {window}{fit_bytes} B/stage state "
                             f"fits only after checkpointing {len(victims)} "

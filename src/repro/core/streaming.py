@@ -38,14 +38,16 @@ stage axis.
 DEGREE-AWARE HYBRID STATE (``init_hybrid_state``/``ingest_block_hybrid``)
 escapes the n²/8 wall for sparse streams: full bitset rows only for
 high-degree hubs (promoted when their streamed degree crosses a threshold
-or their buffer would overflow), compacted sorted-adjacency buffers of C
-neighbor slots for the long tail — ``4·(H·W + n·(C+2))`` bytes, linear in
-n. The two-phase blocked contract is preserved exactly: phase 1 gathers
-full-width rows for the block's endpoints only, phase 2 runs in a packed
-block-local vertex space, and ``pre + mixed//2 + dd//3`` is bit-identical
-to the dense state (pinned by tests/test_hybrid_stream.py's randomized
-differential harness). Capacity exhaustion is counted in ``lost`` and
-raises at finalize — never a silent undercount.
+or their buffer would overflow), buffers of C neighbor slots for the long
+tail — ``4·(H·W + n·(C+2))`` bytes, linear in n. The two-phase blocked
+contract is preserved exactly without a full-width row for any tail
+vertex: phase 1 closes each edge by its endpoints' classes (two hub rows,
+a tail list tested as hub words, or two lists merged), phase 2 counts the
+block's own wedges from lists and hub words, and ``pre + mixed//2 +
+dd//3`` is bit-identical to the dense state (pinned by
+tests/test_hybrid_stream.py's randomized differential harness and
+tests/test_hybrid_ingest.py). Capacity exhaustion is counted in ``lost``
+and raises at finalize — never a silent undercount.
 
 SLIDING WINDOWS (``init_windowed_state``/``ingest_block_windowed``/
 ``expire_epoch``) extend the same contract with deletions: the state is a
@@ -73,7 +75,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.tracing import INGEST_AGE_CUM, INGEST_LIVE, INGEST_TERMS, INGEST_UPDATE
+from repro.tracing import (INGEST_AGE_CUM, INGEST_LIVE, INGEST_PROMOTE,
+                           INGEST_TERMS, INGEST_UPDATE)
 from repro.utils import count_dtype
 
 # The blocked-kernel path keeps the whole mask table VMEM-resident and the
@@ -284,32 +287,57 @@ def _stage_seen(adj_s: jax.Array, lo: jax.Array, hi: jax.Array, off) -> jax.Arra
         return jnp.where(owned, bit, jnp.uint32(0))
 
 
+def _row_windows(n_rows: int, width: int, row: jax.Array, col: jax.Array,
+                 val: jax.Array, ok: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """One-hot windows that add ``val[i]`` at ``(row[i], col[i])`` of an
+    (n_rows, width) table, for :func:`_write_bits`: ``(at, win)`` with
+    ``win[i]`` the L words around the entry and ``at[i]`` the lane row it
+    is added to. A table of whole (8, 128) tiles, the TPU's layout for it,
+    has L = 128 and lane rows in the order it is stored in: tile by tile,
+    then sublane by sublane. Any other table has its whole rows as lane rows
+    (L = width). Entries that are not ``ok`` get ``at`` = n_rows·width/L,
+    one past the last lane row, so the scatter drops them. The windows are
+    a broadcast compare: no table is built."""
+    lanes = _LANES if n_rows % 8 == 0 and width % _LANES == 0 else width
+    # tile (row // 8, col // lanes), sublane row % 8; whole rows: at = row
+    at = ((row // 8) * (width // lanes) + col // lanes) * 8 + row % 8
+    win = jnp.where(
+        ok[:, None] & (jnp.arange(lanes, dtype=col.dtype)[None, :]
+                       == (col % lanes)[:, None]),
+        val[:, None], jnp.zeros((), val.dtype))
+    return jnp.where(ok, at, n_rows * width // lanes), win
+
+
 def _block_bits(n: int, ws: int, lo: jax.Array, hi: jax.Array,
                 live: jax.Array, off) -> tuple[jax.Array, jax.Array]:
-    """The block's delta-adjacency on this stage's word shard as 2B one-hot
-    windows for :func:`_write_bits`: ``(at, win)`` with ``win[i]`` the L
-    words holding one edge's bit and ``at[i]`` the lane row it is added to.
-    A table of whole (8, 128) tiles, the TPU's layout for it, has L = 128
-    and lane rows in the order it is stored in: tile by tile, then sublane
-    by sublane. Any other table has its whole rows as lane rows (L = W_s).
-    The ``lo→hi`` bits come first, then ``hi→lo``. Dead edges and bits of
-    words another stage owns get ``at`` = n·W_s/L, one past the last lane
-    row, so the scatter drops them. The windows are a broadcast compare: no
-    (n, W_s) table is built."""
+    """The block's delta-adjacency on this stage's word shard as 2B
+    :func:`_row_windows`, one bit each: the ``lo→hi`` bits first, then
+    ``hi→lo``. Dead edges and bits of words another stage owns are
+    dropped. No (n, W_s) table is built."""
     with jax.named_scope(INGEST_UPDATE):
         row = jnp.concatenate([lo, hi])
         col = jnp.concatenate([hi, lo])
         wl = col // 32 - off
         ok = jnp.concatenate([live, live]) & (wl >= 0) & (wl < ws)
-        lanes = _LANES if n % 8 == 0 and ws % _LANES == 0 else ws
-        # tile (row // 8, wl // lanes), sublane row % 8; whole rows: at = row
-        at = ((row // 8) * (ws // lanes) + wl // lanes) * 8 + row % 8
         bit = jnp.uint32(1) << (col % 32).astype(jnp.uint32)
-        win = jnp.where(
-            ok[:, None] & (jnp.arange(lanes, dtype=wl.dtype)[None, :]
-                           == (wl % lanes)[:, None]),
-            bit[:, None], jnp.uint32(0))
-        return jnp.where(ok, at, n * ws // lanes), win
+        return _row_windows(n, ws, row, wl, bit, ok)
+
+
+def _lane_view(table: jax.Array):
+    """(lane rows, back): ``table`` (..., n, W_s) as the (R, L) lane rows
+    that :func:`_row_windows` addresses, and the function that turns them
+    back into the table. On the TPU both are bitcasts."""
+    *lead, n, ws = table.shape
+    lanes = _LANES if n % 8 == 0 and ws % _LANES == 0 else ws
+    if lanes == ws:
+        return table.reshape(-1, ws), lambda rows: rows.reshape(table.shape)
+    tiles = table.reshape(*lead, n // 8, 8, ws // lanes, lanes)
+    rows = jnp.swapaxes(tiles, -3, -2).reshape(-1, lanes)
+
+    def back(rows):
+        tiles = rows.reshape(*lead, n // 8, ws // lanes, 8, lanes)
+        return jnp.swapaxes(tiles, -3, -2).reshape(table.shape)
+    return rows, back
 
 
 def _write_bits(table: jax.Array, at: jax.Array, win: jax.Array) -> jax.Array:
@@ -318,17 +346,9 @@ def _write_bits(table: jax.Array, at: jax.Array, win: jax.Array) -> jax.Array:
     lane-row view of a tiled table is a bitcast, so the write is in place
     and moves 512 bytes an update. The bits are distinct and unset in
     ``table``: ``add`` is ``or``."""
-    *lead, n, ws = table.shape
-    lanes = win.shape[1]
     with jax.named_scope(INGEST_UPDATE):
-        if lanes == ws:
-            rows = table.reshape(-1, ws).at[at].add(win, mode="drop")
-            return rows.reshape(table.shape)
-        tiles = table.reshape(*lead, n // 8, 8, ws // lanes, lanes)
-        rows = jnp.swapaxes(tiles, -3, -2).reshape(-1, lanes)
-        rows = rows.at[at].add(win, mode="drop")
-        tiles = rows.reshape(*lead, n // 8, ws // lanes, 8, lanes)
-        return jnp.swapaxes(tiles, -3, -2).reshape(table.shape)
+        rows, back = _lane_view(table)
+        return back(rows.at[at].add(win, mode="drop"))
 
 
 def _kernel_fits(use_kernel: bool, table_bytes: int, n_edges: int) -> bool:
@@ -870,29 +890,37 @@ def ingest_block_per_edge(state: dict, edges: jax.Array) -> dict:
 # --------------------------------------------------------------------------
 def init_hybrid_state(n_nodes: int, hub_slots: int, tail_capacity: int) -> dict:
     """Hybrid streaming state: ``hub_slots`` full bitset rows reserved for
-    high-degree vertices plus a compacted sorted-adjacency buffer of
-    ``tail_capacity`` neighbor slots per vertex for the long tail.
+    high-degree vertices plus a buffer of ``tail_capacity`` neighbor slots
+    per vertex for the long tail.
 
-    Layout (all int32/uint32):
+    Layout:
 
-    - ``hub_adj``  (H, W)  — one full-width bitset row per hub slot
-    - ``hub_ids``  (H,)    — vertex owning each slot (sentinel n = free)
-    - ``hub_slot`` (n,)    — slot index per vertex (-1 = tail vertex)
-    - ``tail_nbr`` (n, C)  — sorted neighbor ids, sentinel n past the fill
-    - ``deg``      (n,)    — streamed degree so far (the promotion sketch)
+    - ``hub_adj``  (H, W) uint32 — one full-width bitset row per hub slot
+    - ``hub_ids``  (H,)   — vertex owning each slot (sentinel n = free)
+    - ``hub_slot`` (n,)   — slot index per vertex (-1 = tail vertex)
+    - ``tail_nbr`` (n, C) — a tail vertex's neighbor ids in arrival order,
+      sentinel n past the fill (a hub's row is all sentinel)
+    - ``deg``      (n,)   — streamed degree so far (the promotion sketch)
     - ``count``            — running triangle total; ``lost`` — edge
       endpoints DROPPED on capacity exhaustion (must stay 0; the serving
       tier raises loudly otherwise — never a silent undercount)
+    - counters, in ``count_dtype``: ``promoted`` (tail vertices given a hub
+      row), the live edges by the class of their endpoints when they
+      arrived (``pairs_hub`` hub–hub, ``pairs_mixed`` hub–tail,
+      ``pairs_tail`` tail–tail), and the filled tail entries those edges
+      read (``fill_mixed``: the tail end's, each tested as a bit of the
+      hub's row; ``fill_tail``: both ends', intersected)
 
-    State bytes: ``4·(H·W + H + n·(C+2)) + O(1)`` (:func:`hybrid_state_nbytes`
-    is the exact planner-side formula) — linear in n instead of the dense
-    n²/8 whenever C ≪ n/8. Allocation only; traces nothing."""
+    State bytes: ``4·(H·W + H + n·(C+2))`` plus the scalars
+    (:func:`hybrid_state_nbytes` is the exact planner-side formula) — linear
+    in n instead of the dense n²/8 whenever C ≪ n/8. Allocation only;
+    traces nothing."""
     if hub_slots < 1:
         raise ValueError(f"hub_slots must be >= 1, got {hub_slots}")
     if tail_capacity < 1:
         raise ValueError(f"tail_capacity must be >= 1, got {tail_capacity}")
     w = -(-n_nodes // 32)
-    return {
+    state = {
         "hub_adj": jnp.zeros((hub_slots, w), jnp.uint32),
         "hub_ids": jnp.full((hub_slots,), n_nodes, jnp.int32),
         "hub_slot": jnp.full((n_nodes,), -1, jnp.int32),
@@ -901,6 +929,14 @@ def init_hybrid_state(n_nodes: int, hub_slots: int, tail_capacity: int) -> dict:
         "count": jnp.zeros((), count_dtype()),
         "lost": jnp.zeros((), jnp.int32),
     }
+    for k in HYBRID_COUNTERS:
+        state[k] = jnp.zeros((), count_dtype())
+    return state
+
+
+# the hybrid state's counters beside ``count`` and ``lost`` (init_hybrid_state)
+HYBRID_COUNTERS = ("promoted", "pairs_hub", "pairs_mixed", "pairs_tail",
+                   "fill_mixed", "fill_tail")
 
 
 def hybrid_state_nbytes(n_nodes: int, hub_slots: int, tail_capacity: int) -> int:
@@ -911,54 +947,159 @@ def hybrid_state_nbytes(n_nodes: int, hub_slots: int, tail_capacity: int) -> int
     w = -(-n_nodes // 32)
     scalar = int(np.dtype(count_dtype()).itemsize)
     return 4 * (hub_slots * w + hub_slots + n_nodes * (tail_capacity + 2)) \
-        + scalar + 4
+        + scalar * (1 + len(HYBRID_COUNTERS)) + 4
 
 
-def _tail_rows(nbrs: jax.Array, n: int, w: int) -> jax.Array:
-    """(R, C) sorted tail neighbor buffers -> (R, W) full-width bitset rows.
+# The hybrid ingest sorts each edge class by how much it reads and cuts it
+# into this many bands of rows; each band runs at the smallest width that
+# holds its largest row (a ``lax.switch``), so the reads follow the fill.
+_BANDS = 8
 
-    The sentinel column is mapped to word W EXPLICITLY (scatter drop): the
-    naive ``n // 32`` is a REAL word index whenever ``n % 32 != 0``, so
-    relying on the id itself being out of range would corrupt bit n%32 of
-    the last word."""
-    r = nbrs.shape[0]
-    real = nbrs < n
-    col = jnp.where(real, nbrs // 32, w)
-    bit = jnp.where(real, jnp.uint32(1) << (nbrs % 32).astype(jnp.uint32),
-                    jnp.uint32(0))
-    # buffer entries are distinct neighbors, so add == bitwise-or
-    return jnp.zeros((r, w), jnp.uint32).at[
-        jnp.arange(r)[:, None], col].add(bit)
+
+def _widths(cap: int) -> tuple:
+    """A band's widths: 0 (nothing to read), then powers of two from 8 up
+    to ``cap``."""
+    out, w = [0], 8
+    while w < cap:
+        out.append(w)
+        w *= 2
+    return tuple(out + [cap])
+
+
+def _sizes(cap: int, steps=(8, 64, 512)) -> tuple:
+    """Compaction sizes: 0, then ``steps`` below ``cap``, then ``cap``."""
+    return tuple(sorted({0, cap, *(s for s in steps if s < cap)}))
+
+
+def _pick(sizes: tuple, need) -> jax.Array:
+    """Index of the smallest of ``sizes`` (ascending, from 0) that holds
+    ``need``: the ``lax.switch`` branch of a band or compaction."""
+    return sum((need > s).astype(jnp.int32) for s in sizes[:-1])
+
+
+def _banded_sum(fill: jax.Array, valid: jax.Array, widths: tuple, body,
+                *rows) -> jax.Array:
+    """Σ over bands of ``body(width, ok, *band_rows)``. The rows are sorted
+    valid first by ``fill`` descending and cut into :data:`_BANDS` bands;
+    a band runs at the smallest of ``widths`` that holds its first (largest)
+    fill, and width 0 (no valid row with a fill) reads nothing. ``rows``
+    are per-row arrays, gathered once into the sorted order."""
+    b = fill.shape[0]
+    per = -(-b // _BANDS)
+    order = jnp.argsort(jnp.where(valid, -fill, 1), stable=True)
+    order = jnp.concatenate([order, jnp.zeros((per * _BANDS - b,), order.dtype)])
+    ok = jnp.arange(per * _BANDS) < jnp.sum(valid.astype(jnp.int32))
+    top = jnp.where(ok, fill[order], 0)
+    rows = [r[order] for r in rows]
+    branches = [partial(body, w) for w in widths]
+    total = jnp.zeros((), count_dtype())
+    for k in range(_BANDS):
+        band = slice(k * per, (k + 1) * per)
+        total = total + jax.lax.switch(_pick(widths, top[k * per]), branches,
+                                       ok[band], *(r[band] for r in rows))
+    return total
+
+
+def _masked_total(x: jax.Array, mask: jax.Array) -> jax.Array:
+    return jnp.sum(jnp.where(mask, x, 0), dtype=count_dtype())
+
+
+def _popcount_total(words: jax.Array, mask: jax.Array) -> jax.Array:
+    return _masked_total(jax.lax.population_count(words).sum(axis=-1), mask)
+
+
+def _hub_bits(hub_adj: jax.Array, slot: jax.Array, col: jax.Array) -> jax.Array:
+    """Bit ``col`` of hub row ``slot``, elementwise: one word gathered from
+    the table per entry (``slot`` and ``col`` broadcast)."""
+    h, w = hub_adj.shape
+    word = hub_adj[jnp.clip(slot, 0, h - 1), jnp.clip(col // 32, 0, w - 1)]
+    return (word >> (col % 32).astype(jnp.uint32)) & jnp.uint32(1)
+
+
+def _wedge_terms(g: int, hub_adj: jax.Array, so: jax.Array,
+                 gstart: jax.Array, deg_b: jax.Array, lists: jax.Array,
+                 o_hub: jax.Array, o_slot: jax.Array) -> jax.Array:
+    """Phase 2's mixed term for block-local degrees up to ``g``: Σ over
+    ordered pairs (a, b) of live occurrences of one vertex (its block edges
+    to ``so[a]`` and ``so[b]``) of A[so[a], so[b]] in the pre-block state.
+
+    Occurrences are sorted by vertex (each group from ``gstart``, size
+    ``deg_b``, 0 for a dead occurrence); ``lists[a]`` is ``so[a]``'s tail list (all sentinel for a
+    hub), ``o_hub``/``o_slot`` its class and slot. A pair with a tail end is
+    read from that end's list, both ways; a hub–hub pair from one word of
+    the first hub's row, compacted first. Returns the (tail, any) hits plus
+    the (tail, hub) hits — the (hub, tail) pairs by symmetry — plus twice
+    the hub–hub closures."""
+    m = so.shape[0]
+    # the other members of a's group: a window of g from its group's start
+    at = jnp.clip(gstart[:, None] + jnp.arange(g)[None, :], 0, m - 1)
+    same = ((jnp.arange(g)[None, :] < deg_b[:, None])
+            & (at != jnp.arange(m)[:, None]))
+    part = jnp.where(same, so[at], -1)
+    phub = o_hub[at]
+    # (2B, C, g) compare, reduced over the list: never materialized
+    hit = jnp.any(lists[:, :, None] == part[:, None, :], axis=1)
+    tails = (jnp.sum(hit, dtype=count_dtype())
+             + jnp.sum(hit & phub, dtype=count_dtype()))
+    # each unordered hub–hub pair once: from a to the members after it
+    pairs = same & o_hub[:, None] & phub & (at > jnp.arange(m)[:, None])
+    flat = pairs.reshape(-1)
+    sizes = _sizes(flat.shape[0], steps=(256, 2048, 16384))
+    order = jnp.argsort(~flat, stable=True)
+    cols = pairs.shape[1]
+    partner = part.reshape(-1)
+
+    def lookups(k, order):
+        if k == 0:
+            return jnp.zeros((), count_dtype())
+        idx = order[:k]
+        bit = _hub_bits(hub_adj, o_slot[idx // cols], partner[idx])
+        return _masked_total(bit, flat[idx])
+
+    hh = jax.lax.switch(_pick(sizes, jnp.sum(flat.astype(jnp.int32))),
+                        [partial(lookups, k) for k in sizes], order)
+    return tails + 2 * hh
 
 
 def _ingest_block_hybrid_impl(state: dict, edges: jax.Array, *,
                               hub_threshold: int) -> dict:
     """Fold one (B, 2) int32 edge block into the HYBRID state — the same
     two-phase ``pre + mixed//2 + dd//3`` contract as ``ingest_block``, bit
-    for bit, without ever materializing an (n, W) table.
+    for bit, without ever building a full-width row for a tail vertex.
 
-    Phase 1 gathers full-width pre-block rows for the 2B endpoints only
-    (hub rows verbatim, tail buffers expanded via :func:`_tail_rows`) and
-    popcounts closures. Phase 2 works in a BLOCK-LOCAL vertex space: the
-    block delta D only ever touches block endpoints, so D and the
-    restriction of A to block-vertex columns are packed into (2B, ceil(2B/32))
-    words and the exact dense multiplicities carry over unchanged (mixed
-    counts each (block, block, pre-block) triangle twice, dd each
-    all-in-block triangle three times).
+    Each endpoint's tail list is gathered once ((2B, C) entries). The
+    already-seen check reads one hub word per edge, or the lo end's list.
+    Phase 1 closes each live edge by the class of its endpoints:
 
-    PROMOTION runs before insertion: a tail vertex whose streamed degree
-    would exceed its buffer (mandatory) or reaches ``hub_threshold``
-    (policy) claims a free hub slot — its buffer is expanded into the slot's
-    bitset row and cleared — with mandatory promotions outranking policy
-    ones when slots are scarce. Only when every slot is taken AND a buffer
-    still overflows are edge endpoints dropped, counted in ``lost`` (the
-    serving tier refuses to finalize a lossy session).
+    - hub–hub: the two hub rows, AND and popcount;
+    - hub–tail: the tail list's entries tested as bits of the hub row, one
+      word gathered per entry;
+    - tail–tail: the two lists merged (a sort of their concatenation) and
+      equal neighbours counted.
 
-    Transient working set: ~8 full-width row-gathers of B edges (32·B·W
-    bytes) plus the (2B)² local bit matrix — the planner's hybrid block
-    sizing keeps both inside the memory budget. Trace contract: one trace
-    per (block shape, n, H, C, threshold) — module-level jit, shared across
-    sessions; promotion and degree updates are data, never a retrace."""
+    Each class is sorted by what it reads and cut into bands, each run at
+    the width its largest row needs (:func:`_banded_sum`), so a class costs
+    about its filled entries, and hub rows are gathered for hub–hub edges
+    only. Phase 2 works on the block's own occurrences sorted by vertex:
+    ``mixed`` counts, for each pair of block edges at one vertex, the
+    pre-block edge between their other ends, read from a tail list or a hub
+    word (:func:`_wedge_terms`); ``dd`` is the block's own triangles, from
+    block-local bitsets of (2B, 2B) bits.
+
+    PROMOTION (scope ``ingest.promote``): a tail vertex whose streamed
+    degree would exceed its buffer (mandatory) or reaches ``hub_threshold``
+    (policy) claims a free hub slot, mandatory first, at most min(64, 2B) a
+    block (a policy promotion past that waits for a later block). Its row
+    is written from its pre-block list as one-hot windows, into a free slot
+    that no term reads, and its list is cleared. Insertion then writes hub
+    bits and tail appends as one-hot windows into the donated tables' lane
+    rows (:func:`_write_bits`), in place. Only when a tail buffer is full
+    and no slot can be had are endpoints dropped, counted in ``lost``.
+
+    The compiled ingest has no loop: every data-dependent size is a
+    ``lax.switch`` over static sizes. Trace contract: one trace per (block
+    shape, n, H, C, threshold) — module-level jit, shared across sessions;
+    promotion and degree updates are data, never a retrace."""
     _INGEST_TRACES[0] += 1
     hub_adj, hub_ids = state["hub_adj"], state["hub_ids"]
     hub_slot, tail_nbr, deg = state["hub_slot"], state["tail_nbr"], state["deg"]
@@ -966,141 +1107,181 @@ def _ingest_block_hybrid_impl(state: dict, edges: jax.Array, *,
     h, w = hub_adj.shape
     c = tail_nbr.shape[1]
     b = edges.shape[0]
+    m = 2 * b  # endpoint occurrences: the lo ends, then the hi ends
 
     keep, lo, hi = _canonical_live(edges, n)
-
-    def full_rows(v):
-        # (B, W) pre-block adjacency rows (phantom id n -> zero row)
-        gv = jnp.clip(v, 0, n - 1)
-        slot = jnp.where(v < n, hub_slot[gv], -1)
-        hubrow = hub_adj[jnp.clip(slot, 0, h - 1)]
-        tailrow = _tail_rows(tail_nbr[gv], n, w)
-        rows = jnp.where((slot >= 0)[:, None], hubrow, tailrow)
-        return jnp.where((v < n)[:, None], rows, jnp.uint32(0))
-
-    with jax.named_scope(INGEST_TERMS):
-        rows_lo = full_rows(lo)
-        rows_hi = full_rows(hi)
-
     with jax.named_scope(INGEST_LIVE):
-        # dedup against A: bit hi of lo's row (rows are symmetric by insertion)
-        word = rows_lo[jnp.arange(b), jnp.clip(hi // 32, 0, w - 1)]
-        seen = (word >> (hi % 32).astype(jnp.uint32)) & jnp.uint32(1)
-        live = keep & (seen == 0)
-
-    def masked_sum(words, mask):
-        pc = jax.lax.population_count(words).sum(axis=-1)
-        return jnp.sum(jnp.where(mask, pc, 0), dtype=count_dtype())
+        vo = jnp.concatenate([lo, hi])
+        go = jnp.clip(vo, 0, n - 1)
+        slot_o = jnp.where(vo < n, hub_slot[go], -1)
+        deg_o = jnp.where(vo < n, deg[go], 0)
+        fill_o = jnp.where(slot_o < 0, jnp.minimum(deg_o, c), 0)
+        lists = jnp.where((vo < n)[:, None], tail_nbr[go], n)   # (2B, C)
+        s_lo, s_hi = slot_o[:b], slot_o[b:]
+        f_lo, f_hi = fill_o[:b], fill_o[b:]
+        # already in A: one hub word, else the lo end's list holds hi
+        chk = s_lo >= 0
+        hbit = _hub_bits(hub_adj, jnp.where(chk, s_lo, s_hi),
+                         jnp.where(chk, hi, lo))
+        listed = jnp.any(lists[:b] == hi[:, None], axis=1)
+        seen = jnp.where(chk | (s_hi >= 0), hbit == 1, listed)
+        # the word reads done before the table enters the promotion's
+        # switch, which then writes it in place instead of copying it
+        live, hub_adj = jax.lax.optimization_barrier((keep & ~seen, hub_adj))
 
     with jax.named_scope(INGEST_TERMS):
-        pre = masked_sum(rows_lo & rows_hi, live)
-
-        # ---- block-local vertex space for the intra-block correction ----
-        big = 2 * b
-        wl = -(-big // 32)
-        rlo = jnp.where(live, lo, n)
-        rhi = jnp.where(live, hi, n)
-        verts = jnp.concatenate([rlo, rhi])      # one occurrence per endpoint
-        others = jnp.concatenate([rhi, rlo])     # the occurrence's neighbor
-        liveo = jnp.concatenate([live, live])
+        # the block's occurrences sorted by vertex: phase 2 and promotion
+        row = jnp.arange(b)
+        live2 = jnp.concatenate([live, live])
+        verts = jnp.where(live2, vo, n)
+        opp = jnp.concatenate([row + b, row])           # the other end's row
         order = jnp.argsort(verts, stable=True)
+        oo = opp[order]
         sv = verts[order]
+        so = vo[oo]
+        idx = jnp.arange(m)
         firsts = jnp.concatenate([jnp.ones((1,), bool), sv[1:] != sv[:-1]])
-        lid_sorted = (jnp.cumsum(firsts) - 1).astype(jnp.int32)
-        lid = jnp.zeros((big,), jnp.int32).at[order].set(lid_sorted)
-        # global vertex per local id (dead occurrences share the id of value n)
-        gvert = jnp.full((big,), n, jnp.int32).at[lid_sorted].set(sv)
+        lasts = jnp.concatenate([sv[1:] != sv[:-1], jnp.ones((1,), bool)])
+        gid = (jnp.cumsum(firsts) - 1).astype(jnp.int32)
+        gstart = jax.lax.cummax(jnp.where(firsts, idx, 0))
+        gend = jax.lax.cummin(jnp.where(lasts, idx + 1, m), reverse=True)
+        real = sv < n
+        deg_b = jnp.where(real, gend - gstart, 0)
+        o_slot = slot_o[oo]
 
-        # D in local space: each live edge's two bits, one scatter each way
-        l_lo, l_hi = lid[:b], lid[b:]
-
-        def dscat(dst, row, cvert):
-            rr = jnp.where(live, row, big)  # dead edges scatter out of bounds
-            bit = jnp.where(live, jnp.uint32(1) << (cvert % 32).astype(jnp.uint32),
-                            jnp.uint32(0))
-            return dst.at[rr, cvert // 32].add(bit)
-
-        dloc = dscat(dscat(jnp.zeros((big, wl), jnp.uint32), l_lo, l_hi), l_hi, l_lo)
-
-        # A restricted to block-vertex columns, per occurrence, packed to words
-        rows_cat = jnp.concatenate([rows_lo, rows_hi])          # (2B, W)
-        gw = jnp.clip(gvert // 32, 0, w - 1)
-        abit = (rows_cat[:, gw] >> (gvert % 32).astype(jnp.uint32)[None, :]) \
-            & jnp.uint32(1)                                      # (2B, L)
-        abit = jnp.where((gvert < n)[None, :], abit, jnp.uint32(0))
-        abit = jnp.pad(abit, ((0, 0), (0, wl * 32 - big)))
-        aloc = (abit.reshape(big, wl, 32)
-                << jnp.arange(32, dtype=jnp.uint32)[None, None, :]).sum(
-            axis=-1, dtype=jnp.uint32)                           # (2B, Wl)
-
-        d_lo = dloc[jnp.clip(l_lo, 0, big - 1)]
-        d_hi = dloc[jnp.clip(l_hi, 0, big - 1)]
-        mixed = masked_sum(aloc[:b] & d_hi, live) + masked_sum(d_lo & aloc[b:], live)
-        dd = masked_sum(d_lo & d_hi, live)
-    count = _combine(state["count"], jnp.stack([pre, mixed, dd]))
-
-    with jax.named_scope(INGEST_UPDATE):
-        # ---- promotion (BEFORE insertion, on pre-block buffers) ----
-        occ = jnp.zeros((big,), jnp.int32).at[jnp.where(liveo, lid, big)].add(1)
-        real = gvert < n
-        gv_ok = jnp.clip(gvert, 0, n - 1)
-        is_tail = jnp.where(real, hub_slot[gv_ok] < 0, False)
-        newdeg = jnp.where(real, deg[gv_ok], 0) + occ
-        touched = is_tail & (occ > 0)
-        must = touched & (newdeg > c)            # buffer would overflow
-        want = touched & (newdeg >= hub_threshold)
-        cand = must | want
+    with jax.named_scope(INGEST_PROMOTE):
+        # before the terms: the promoted rows go into free slots, which no
+        # read of the pre-block state touches, so the table is written in
+        # place while no other conditional holds it. One candidate per
+        # distinct vertex: its first occurrence in ``order``
+        tail_first = firsts & real & (slot_o[order] < 0)
+        newdeg = deg_o[order] + deg_b
+        must = tail_first & (newdeg > c)            # buffer would overflow
+        cand = must | (tail_first & (newdeg >= hub_threshold))
         free = hub_ids == n
         n_free = jnp.sum(free.astype(jnp.int32))
-        # mandatory promotions claim free slots before policy ones
-        mrank = jnp.cumsum(must.astype(jnp.int32)) - 1
-        wrank = jnp.sum(must.astype(jnp.int32)) \
-            + jnp.cumsum((cand & ~must).astype(jnp.int32)) - 1
-        prank = jnp.where(must, mrank, wrank)
-        slot_for = jnp.argsort(~free, stable=True)[jnp.clip(prank, 0, h - 1)]
-        ok = cand & (prank < n_free) & (prank < h)
+        n_must = jnp.sum(must.astype(jnp.int32))
+        prank = jnp.where(must, jnp.cumsum(must.astype(jnp.int32)) - 1,
+                          n_must + jnp.cumsum((cand & ~must).astype(jnp.int32)) - 1)
+        sizes = _sizes(min(64, m))
+        ok = cand & (prank < n_free) & (prank < sizes[-1])
+        free_order = jnp.argsort(~free, stable=True)
+        chosen = jnp.argsort(~ok, stable=True)[:sizes[-1]]  # promoted first
+        okp = ok[chosen]
+        v_p = jnp.where(okp, sv[chosen], n)
+        slot_p = jnp.where(okp, free_order[jnp.clip(prank[chosen], 0, h - 1)],
+                           h).astype(jnp.int32)
+        lists_p = lists[order[chosen]]               # pre-block lists
 
-        s_ok = jnp.where(ok, slot_for, h)        # out-of-bounds scatter -> drop
-        v_ok = jnp.where(ok, gvert, n)
-        promo_rows = jnp.where(real[:, None], _tail_rows(tail_nbr[gv_ok], n, w),
-                               jnp.uint32(0))
-        hub_adj = hub_adj.at[s_ok].set(promo_rows)   # free slots hold zero rows
-        hub_ids = hub_ids.at[s_ok].set(v_ok)
-        hub_slot = hub_slot.at[v_ok].set(jnp.where(ok, slot_for, 0).astype(jnp.int32))
-        tail_nbr = tail_nbr.at[v_ok].set(jnp.int32(n))
+        def promote(k, lane_rows):
+            # the promoted rows, written from their lists as one-hot windows
+            # into the table's lane rows (free slots hold zero rows)
+            if k == 0:
+                return lane_rows
+            x = lists_p[:k].reshape(-1)
+            at, win = _row_windows(
+                h, w, jnp.repeat(slot_p[:k], c), x // 32,
+                jnp.uint32(1) << (x % 32).astype(jnp.uint32),
+                jnp.repeat(okp[:k], c) & (x < n))
+            return lane_rows.at[at].add(win, mode="drop")
 
-        # ---- insertion (hub rows get bits, tail buffers get sorted ids) ----
-        slot_now = jnp.where(liveo, hub_slot[jnp.clip(verts, 0, n - 1)], -1)
-        to_hub = liveo & (slot_now >= 0)
-        hbit = jnp.where(to_hub, jnp.uint32(1) << (others % 32).astype(jnp.uint32),
-                         jnp.uint32(0))
+        # the switch takes the table's lane-row view, as _write_bits does
+        lane_rows, unview = _lane_view(hub_adj)
+        hub_adj = unview(jax.lax.switch(
+            _pick(sizes, jnp.sum(ok.astype(jnp.int32))),
+            [partial(promote, k) for k in sizes], lane_rows))
+        hub_ids = hub_ids.at[slot_p].set(v_p, mode="drop")
+        hub_slot = hub_slot.at[v_p].set(slot_p, mode="drop")
+        tail_nbr = tail_nbr.at[v_p].set(
+            jnp.full((sizes[-1], c), n, jnp.int32), mode="drop")
+        promoted = jnp.sum(ok.astype(jnp.int32))
+
+    with jax.named_scope(INGEST_TERMS):
+        hh = live & (s_lo >= 0) & (s_hi >= 0)
+        mixed = live & ((s_lo >= 0) != (s_hi >= 0))
+        tt = live & (s_lo < 0) & (s_hi < 0)
+
+        def hub_pairs(width, ok, sa, sb):
+            if width == 0:
+                return jnp.zeros((), count_dtype())
+            return _popcount_total(hub_adj[jnp.clip(sa, 0, h - 1)]
+                                   & hub_adj[jnp.clip(sb, 0, h - 1)], ok)
+
+        def hub_tail(width, ok, s, lst):
+            if width == 0:
+                return jnp.zeros((), count_dtype())
+            x = lst[:, :width]
+            bit = _hub_bits(hub_adj, s[:, None], x)
+            return _masked_total(bit, ok[:, None] & (x < n))
+
+        def tail_tail(width, ok, la, lb):
+            if width == 0:
+                return jnp.zeros((), count_dtype())
+            x = jnp.sort(jnp.concatenate([la[:, :width], lb[:, :width]], axis=1),
+                         axis=1)
+            both = (x[:, 1:] == x[:, :-1]) & (x[:, 1:] < n)
+            return _masked_total(both, ok[:, None] & both)
+
+        tail_end = jnp.where(s_lo >= 0, row + b, row)   # mixed: the tail's row
+        f_mixed = jnp.where(s_lo >= 0, f_hi, f_lo)
+        pre = (_banded_sum(hh.astype(jnp.int32), hh, (0, 1), hub_pairs, s_lo, s_hi)
+               + _banded_sum(f_mixed, mixed, _widths(c), hub_tail,
+                             jnp.where(s_lo >= 0, s_lo, s_hi), lists[tail_end])
+               + _banded_sum(jnp.maximum(f_lo, f_hi), tt, _widths(c), tail_tail,
+                             lists[:b], lists[b:]))
+
+        # ---- phase 2 ----
+        degrees = (4, 16, 64, m)
+        degrees = tuple(sorted({min(d, m) for d in degrees}))
+        wedge = jax.lax.switch(
+            _pick(degrees, jnp.max(deg_b)),
+            [partial(_wedge_terms, g, hub_adj) for g in degrees],
+            so, gstart, deg_b,
+            jnp.where(real[:, None], lists[oo], n),
+            o_slot >= 0, o_slot)
+
+        # dd: the block's own triangles, thrice, from (2B, 2B) local bitsets
+        lid = jnp.zeros((m,), jnp.int32).at[order].set(gid)
+        wl = -(-m // 32)
+        l_lo, l_hi = lid[:b], lid[b:]
+        at, win = _row_windows(m, wl, lid, lid[opp] // 32,
+                               jnp.uint32(1) << (lid[opp] % 32).astype(jnp.uint32),
+                               live2)
+        dloc = _write_bits(jnp.zeros((m, wl), jnp.uint32), at, win)
+        dd = _popcount_total(dloc[l_lo] & dloc[l_hi], live)
+    count = _combine(state["count"], jnp.stack([pre, wedge, dd]))
+    # every read of the table done before the insertion writes it in place
+    count, hub_adj = jax.lax.optimization_barrier((count, hub_adj))
+
+    with jax.named_scope(INGEST_UPDATE):
+        # ---- insertion: hub bits and tail appends, as one-hot windows ----
+        slot_now = jnp.where(real, hub_slot[jnp.clip(sv, 0, n - 1)], -1)
+        to_hub = real & (slot_now >= 0)
+        at, win = _row_windows(h, w, slot_now, so // 32,
+                               jnp.uint32(1) << (so % 32).astype(jnp.uint32),
+                               to_hub)
         # live edges are deduped and absent from A, so the added bits are
         # distinct and unset: add == bitwise-or (promoted rows included)
-        hub_adj = hub_adj.at[jnp.where(to_hub, slot_now, h),
-                             jnp.clip(others // 32, 0, w - 1)].add(hbit)
+        hub_adj = _write_bits(hub_adj, at, win)
+        to_tail = real & (slot_now < 0)
+        pos = deg_o[order] + (idx - gstart)          # arrival order in the list
+        fits = to_tail & (pos < c)
+        at, win = _row_windows(n, c, sv, pos, so - n, fits)  # sentinel n -> so
+        tail_nbr = _write_bits(tail_nbr, at, win)
+        lost = state["lost"] + jnp.sum((to_tail & ~fits).astype(jnp.int32))
+        deg = deg.at[sv].add(1, mode="drop")
 
-        to_tail = liveo & (slot_now < 0)
-        # arrival rank of each occurrence within its vertex's block segment
-        first_pos = jnp.full((big,), big, jnp.int32).at[lid_sorted].min(
-            jnp.arange(big, dtype=jnp.int32))
-        rank = jnp.zeros((big,), jnp.int32).at[order].set(
-            jnp.arange(big, dtype=jnp.int32) - first_pos[lid_sorted])
-        pos = jnp.where(liveo, deg[jnp.clip(verts, 0, n - 1)], 0) + rank
-        over = to_tail & (pos >= c)              # slot exhausted AND buffer full
-        tail_nbr = tail_nbr.at[jnp.where(to_tail & (pos < c), verts, n),
-                               jnp.clip(pos, 0, c - 1)].set(
-            jnp.where(to_tail, others, n))
-        lost = state["lost"] + jnp.sum(over.astype(jnp.int32))
+        def bump(key, x):
+            return state[key] + jnp.sum(x, dtype=count_dtype())
 
-        # keep touched tail buffers sorted (sentinel n sorts past the fill):
-        # canonical layout -> bit-identical checkpoints regardless of feed order
-        still_tail = real & (hub_slot[gv_ok] < 0) & touched
-        resorted = jnp.sort(tail_nbr[gv_ok], axis=1)
-        tail_nbr = tail_nbr.at[jnp.where(still_tail, gvert, n)].set(resorted)
-
-        deg = deg.at[jnp.where(liveo, verts, n)].add(1)
-    return {"hub_adj": hub_adj, "hub_ids": hub_ids, "hub_slot": hub_slot,
-            "tail_nbr": tail_nbr, "deg": deg, "count": count, "lost": lost}
+        return {"hub_adj": hub_adj, "hub_ids": hub_ids, "hub_slot": hub_slot,
+                "tail_nbr": tail_nbr, "deg": deg, "count": count, "lost": lost,
+                "promoted": bump("promoted", promoted),
+                "pairs_hub": bump("pairs_hub", hh),
+                "pairs_mixed": bump("pairs_mixed", mixed),
+                "pairs_tail": bump("pairs_tail", tt),
+                "fill_mixed": bump("fill_mixed", jnp.where(mixed, f_mixed, 0)),
+                "fill_tail": bump("fill_tail", jnp.where(tt, f_lo + f_hi, 0))}
 
 
 ingest_block_hybrid = partial(jax.jit, static_argnames=("hub_threshold",))(
@@ -1108,6 +1289,17 @@ ingest_block_hybrid = partial(jax.jit, static_argnames=("hub_threshold",))(
 ingest_block_hybrid_donated = partial(
     jax.jit, static_argnames=("hub_threshold",),
     donate_argnums=(0,))(_ingest_block_hybrid_impl)
+
+
+def hybrid_stats(state: dict) -> dict:
+    """Host-synced counters of a hybrid state (or its snapshot): ``lost``,
+    the hub slots in use and :data:`HYBRID_COUNTERS` — the numbers a closed
+    session reports."""
+    n = state["hub_slot"].shape[0]
+    out = {"lost": int(np.asarray(state["lost"])),
+           "hub_slots_used": int(np.sum(np.asarray(state["hub_ids"]) < n))}
+    out.update({k: int(np.asarray(state[k])) for k in HYBRID_COUNTERS})
+    return out
 
 
 def hybrid_lost(state: dict) -> int:

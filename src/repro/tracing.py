@@ -22,6 +22,7 @@ import jax
 INGEST_LIVE = "ingest.live"  # canonical endpoints, block dedup, already-seen bit
 INGEST_AGE_CUM = "ingest.age_cum"  # the windowed age-cumulative OR tables
 INGEST_UPDATE = "ingest.update"  # block delta, state write, count update
+INGEST_PROMOTE = "ingest.promote"  # hybrid: tail vertices given hub rows
 INGEST_TERMS = "ingest.terms"  # row gathers, popcount sums, their reduction
 
 

@@ -320,3 +320,38 @@ def test_triangle_server_batches_small_dense_requests():
     for g, r in zip(graphs, results):
         assert r.item() == count_triangles_brute(g)
     assert any(r.stats.get("batched") for r in results)
+
+
+def test_scale_20_stream_admits_one_hybrid_shape_for_every_session():
+    """One v5e (15.75 GiB): a 2**20-vertex stream gets the hybrid state with
+    512-neighbour tail buffers, 8,192 hub rows and promotion at degree 512
+    (a Graph500 scale-20 graph has 6,196 vertices of degree >= 512), and the
+    second and third concurrent sessions get the same shapes, so one
+    compiled ingest serves them all. The charge is the allocation."""
+    from repro.api import admit_session
+
+    res = Resources(memory_bytes=int(15.75 * 2**30), backend="tpu")
+    first = admit_session(2**20, res)
+    assert first.action == "admit-hybrid"
+    p = first.plan
+    assert (p.hub_slots, p.tail_capacity, p.hub_threshold, p.block_size) == (
+        8192, 512, 512, 2048)
+    assert first.state_bytes == p.predicted_bytes == streaming.hybrid_state_nbytes(
+        2**20, 8192, 512) < 3.3e9
+    for k in (1, 2):
+        more = admit_session(2**20, res, bytes_in_use=k * first.state_bytes)
+        assert more.action == "admit-hybrid"
+        assert more.plan.cache_key() == p.cache_key()
+        assert more.state_bytes == first.state_bytes
+    # past what the whole-device shapes fit, the remainder sizes the state
+    last = admit_session(2**20, res, bytes_in_use=res.memory_bytes - (1 << 30))
+    assert last.action == "admit-hybrid" and last.state_bytes <= 1 << 30
+    assert last.plan.hub_slots < p.hub_slots
+
+
+def test_hybrid_state_nbytes_equals_the_allocation_with_counters():
+    for n, h, cap in [(97, 8, 4), (4096, 256, 16), (2**14, 512, 64)]:
+        state = streaming.init_hybrid_state(n, h, cap)
+        assert streaming.state_nbytes(streaming.snapshot_state(state)) \
+            == streaming.hybrid_state_nbytes(n, h, cap)
+        assert set(streaming.HYBRID_COUNTERS) <= set(state)

@@ -1,7 +1,7 @@
 """Randomized differential harness for the degree-aware hybrid stream state.
 
 The hybrid layout (``core.streaming.init_hybrid_state``) keeps full bitset
-rows only for promoted hubs and fixed-capacity sorted buffers for the tail,
+rows only for promoted hubs and fixed-capacity buffers for the tail,
 so its exactness is a real claim that needs adversarial inputs: power-law
 degree skew (promotion under pressure), dense G(n,p) (everything wants to be
 a hub), star graphs (one mandatory promotion), plus the stream-shape hazards

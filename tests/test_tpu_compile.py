@@ -179,3 +179,35 @@ def test_ingest_writes_the_state_in_place(topo, layout):
     assert mem.alias_size_in_bytes >= state_bytes, mem
     assert mem.temp_size_in_bytes <= limit, (mem.temp_size_in_bytes, limit)
     assert f"u32[{n * ws}]" not in compiled.as_text()
+
+
+def test_hybrid_ingest_at_the_scale_20_cell(one_chip):
+    """The shapes admission gives a 2**20-vertex stream on one v5e (the
+    s20-hybrid-tenants2 cell), in 64 bits: both tables alias the output,
+    neither is copied, the temp stays within the working set the planner
+    sizes the block by (690 MB; under 1 GiB), and the program has no loop
+    (every data-dependent size is a switch over static sizes)."""
+    from repro.api.planner import Resources, admit_session, hybrid_block_bytes
+
+    p = admit_session(2**20, Resources(memory_bytes=int(15.75 * 2**30),
+                                       backend="tpu")).plan
+    assert (p.state_layout, p.hub_slots, p.tail_capacity, p.block_size) == (
+        "hybrid", 8192, 512, 2048)
+    with jax.enable_x64(True):
+        state = _shapes(jax.eval_shape(lambda: streaming.init_hybrid_state(
+            2**20, p.hub_slots, p.tail_capacity)), one_chip)
+        edges = jax.ShapeDtypeStruct((p.block_size, 2), jnp.int32, sharding=one_chip)
+        compiled = streaming.ingest_block_hybrid_donated.lower(
+            state, edges, hub_threshold=p.hub_threshold).compile()
+        nbytes = streaming.hybrid_state_nbytes(2**20, p.hub_slots, p.tail_capacity)
+    mem = compiled.memory_analysis()
+    text = compiled.as_text()
+    assert mem.alias_size_in_bytes >= nbytes > 3e9
+    limit = hybrid_block_bytes(p.block_size, 2**20, p.tail_capacity)
+    assert mem.temp_size_in_bytes <= limit < 1 << 30, (mem.temp_size_in_bytes, limit)
+    assert "while" not in text
+    tables = ("u32[8192,32768]", "s32[1048576,512]", "u32[2097152,128]",
+              "s32[4194304,128]")  # the tables and their lane-row views
+    copies = [line for line in text.splitlines() if " copy(" in line
+              and line.split(" = ", 1)[1].startswith(tables)]
+    assert not copies, copies[:2]

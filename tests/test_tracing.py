@@ -75,8 +75,9 @@ def test_span_names_and_arguments():
     with sp:
         pass
     assert {tracing.INGEST_LIVE, tracing.INGEST_AGE_CUM, tracing.INGEST_UPDATE,
-            tracing.INGEST_TERMS} == {"ingest.live", "ingest.age_cum",
-                                      "ingest.update", "ingest.terms"}
+            tracing.INGEST_TERMS, tracing.INGEST_PROMOTE} == {
+                "ingest.live", "ingest.age_cum", "ingest.update", "ingest.terms",
+                "ingest.promote"}
 
 
 def test_a_span_outside_a_trace_costs_microseconds():
@@ -161,7 +162,8 @@ def _families():
         "windowed_sharded": (lambda: streaming.ingest_block_windowed_sharded_donated.lower(
             streaming.init_windowed_sharded_state(N, 3, 4), e), four | {"ingest.age_cum"}),
         "hybrid": (lambda: streaming.ingest_block_hybrid_donated.lower(
-            streaming.init_hybrid_state(N, 16, 8), e, hub_threshold=6), four),
+            streaming.init_hybrid_state(N, 16, 8), e, hub_threshold=6),
+            four | {"ingest.promote"}),
     }
 
 
